@@ -27,6 +27,8 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 _CODE = textwrap.dedent("""
     import json, time
     import jax, jax.numpy as jnp
+    from repro.runtime import compile_cache
+    compile_cache.configure()
     from repro.core import (gmres, gmres_sharded, gmres_sstep,
                             gmres_sstep_sharded, operators, stencils)
     from repro.compat import make_mesh
@@ -129,13 +131,16 @@ _CODE = textwrap.dedent("""
 
 def main():
     env = dict(os.environ)
+    # Eight fake CPU devices: the child never touches the chip, which
+    # stays with whichever process owns it.
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = SRC
     res = subprocess.run([sys.executable, "-c", _CODE], env=env,
                          capture_output=True, text=True, timeout=1800)
     if res.returncode != 0:
-        print(f"distributed_gmres_FAILED,0,{res.stderr[-200:]!r}")
-        return []
+        raise RuntimeError(f"distributed_gmres child failed "
+                           f"(rc={res.returncode}): {res.stderr[-2000:]}")
     rows = json.loads(res.stdout.strip().splitlines()[-1])
     print("name,us_per_call,derived")
     for r in rows:

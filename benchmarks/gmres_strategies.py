@@ -72,6 +72,8 @@ def run(full: bool = False, m: int = 30, tol: float = 1e-5):
 
 
 def main(full: bool = False):
+    from repro.runtime import compile_cache
+    compile_cache.configure()
     rows = run(full=full)
     print("name,us_per_call,derived")
     for r in rows:

@@ -34,15 +34,27 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ref
-from repro.roofline import HBM_BW, PEAK_FLOPS
+from repro.roofline import chip_peaks
+from repro.runtime import compile_cache
+
+# Modeled rows price traffic and FLOPs on TPU v5e.
+_V5E = chip_peaks("TPU v5 lite")
+HBM_BW, PEAK_FLOPS = _V5E.hbm_bw, _V5E.flops
 
 # Set by main(); families that have a kernel path consult it via _pick().
 MODE = "modeled"
 
 
 def _detect_mode() -> str:
-    """measured on a real accelerator, interpret under CPU emulation."""
-    return "measured" if jax.default_backend() != "cpu" else "interpret"
+    """measured on a TPU, interpret under CPU emulation; any other
+    accelerator has no kernel path to measure."""
+    platform = jax.devices()[0].platform
+    if platform == "tpu":
+        return "measured"
+    if platform == "cpu":
+        return "interpret"
+    raise RuntimeError(f"kernel_bench --measure: no kernel path on "
+                       f"{platform!r} devices (TPU, or CPU interpret)")
 
 
 def _pick(kernel_fn, ref_fn):
@@ -677,6 +689,17 @@ def sharded_rows(cases=((33, 65536, 8), (33, 262144, 8), (65, 65536, 4)),
     return rows
 
 
+def _cpu_child_env() -> dict:
+    """Environment for the HLO-count children: 4 fake CPU devices.  They
+    count collectives in lowered programs and never need the chip, which
+    belongs to this (parent) process."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    return env
+
+
 _PIPE_CODE = textwrap.dedent("""
     import json, sys
     import jax, jax.numpy as jnp
@@ -724,9 +747,7 @@ def _pipelined_hlo_counts(nx: int, m: int):
     benchmarks/distributed_gmres.py).  Raises on failure — the row is the
     PR's acceptance evidence and must not silently degrade to a placeholder.
     """
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = _cpu_child_env()
     res = subprocess.run([sys.executable, "-c", _PIPE_CODE, str(nx), str(m)],
                          env=env, capture_output=True, text=True, timeout=900)
     if res.returncode != 0:
@@ -897,9 +918,7 @@ def _precond_hlo_counts(nx: int, m: int):
     Subprocess so the parent keeps its 1-device view; raises on failure —
     the row is acceptance evidence and must not degrade to a placeholder.
     """
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = _cpu_child_env()
     res = subprocess.run(
         [sys.executable, "-c", _PRECOND_PIPE_CODE, str(nx), str(m)],
         env=env, capture_output=True, text=True, timeout=900)
@@ -1236,6 +1255,7 @@ def _validate_rows(rows):
 def main(json_path: str = "BENCH_kernels.json", smoke: bool = False,
          measure: bool = False):
     global MODE
+    compile_cache.configure()
     MODE = _detect_mode() if measure else "modeled"
     if measure:
         # Autotune-by-measurement: persist the timing winners BEFORE the

@@ -16,6 +16,8 @@ import sys
 
 def main() -> None:
     full = "--full" in sys.argv
+    from repro.runtime import compile_cache
+    compile_cache.configure()
     from benchmarks import (distributed_gmres, gmres_strategies,
                             kernel_bench, roofline_table)
 

@@ -1,63 +1,23 @@
-"""Version-compatibility shims for the jax API surface this repo targets.
+"""Mesh constructors with every axis ``AxisType.Auto``.
 
-The codebase is written against the current mesh API —
-``jax.make_mesh(shape, names, axis_types=(AxisType.Auto, ...))`` — but
-older jax releases predate ``jax.sharding.AxisType`` and the ``axis_types``
-kwarg.  Every call site here wants the fully-Auto default, which is exactly
-what those older releases do unconditionally, so the shim simply drops the
-kwarg when the running jax doesn't know it.
+The solver's sharded paths let the compiler propagate shardings inside
+``shard_map`` bodies, which is what Auto axes mean; these two helpers
+spell that out once instead of at every call site.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types on any jax version."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` over the available devices, all axes Auto."""
     return jax.make_mesh(axis_shapes, axis_names,
-                         axis_types=(axis_type.Auto,) * len(axis_names))
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` on any jax version.
-
-    Newer jax exposes it at the top level with a ``check_vma`` knob; older
-    releases ship ``jax.experimental.shard_map.shard_map`` with the same
-    semantics under ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` normalized to one flat dict.
-
-    Newer jax returns the dict directly; older releases wrap it in a
-    one-element list (one entry per executable).
-    """
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def abstract_mesh(axis_shapes, axis_names):
-    """``jax.sharding.AbstractMesh`` across its two constructor signatures.
-
-    Newer jax: ``AbstractMesh(axis_shapes, axis_names, axis_types=...)``;
-    older jax: ``AbstractMesh(((name, size), ...))``.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.sharding.AbstractMesh(
-            tuple(zip(axis_names, axis_shapes)))
+    """``jax.sharding.AbstractMesh`` of the same shape, all axes Auto."""
     return jax.sharding.AbstractMesh(
         axis_shapes, axis_names,
-        axis_types=(axis_type.Auto,) * len(axis_names))
+        axis_types=(AxisType.Auto,) * len(axis_names))

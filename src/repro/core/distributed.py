@@ -34,7 +34,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.core import operators as op_mod
 from repro.core.gmres import Diagnostics, gmres, GmresResult
 from repro.core.sstep import gmres_sstep
@@ -86,9 +85,10 @@ def _run_sharded(mesh: Mesh, axis: str, op, b, x0, caller: str, body):
 
     Validates divisibility, shards (op, b, x0) by ``shard_specs``, runs
     ``body(op_local, b_local, x0_local) -> GmresResult`` per shard inside
-    the dispatch layer's ``shard_context``, and gathers the solution so
-    callers see the replicated global x.  The entry points below differ
-    ONLY in which shared cycle ``body`` calls.
+    the dispatch layer's ``shard_context``, and returns the global x
+    row-sharded over ``axis`` — each device keeps the shard it solved, so
+    no collective gathers it.  The entry points below differ ONLY in
+    which shared cycle ``body`` calls.
     """
     nshards = mesh.shape[axis]
     n = b.shape[0]
@@ -102,22 +102,19 @@ def _run_sharded(mesh: Mesh, axis: str, op, b, x0, caller: str, body):
 
     def solve_local(op_local, b_local, x0_local):
         with tuning.shard_context(axis, nshards):
-            res = body(op_local, b_local, x0_local)
-            # x is a local shard; gather it so callers see the global x.
-            x_full = lax.all_gather(res.x, axis, tiled=True)
-            return res._replace(x=x_full)
+            return body(op_local, b_local, x0_local)
 
     # Mirrors GmresResult's pytree EXACTLY (including Diagnostics): a new
-    # result field needs a replicated spec here or shard_map rejects the
-    # body's output.  Everything but x is replicated scalars/rings — the
+    # result field needs a spec here or shard_map rejects the body's
+    # output.  Everything but x is replicated scalars/rings — the
     # psum-completed betas are identical on every shard.
     out_specs = GmresResult(
-        x=P(), residual=P(), restarts=P(), converged=P(), inner_steps=P(),
+        x=P(axis), residual=P(), restarts=P(), converged=P(), inner_steps=P(),
         done=P(),
         diagnostics=Diagnostics(status=P(), residual_history=P(),
                                 history_len=P()),
     )
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         solve_local,
         mesh=mesh,
         in_specs=(shard_specs(op, axis), P(axis), P(axis)),
@@ -223,7 +220,8 @@ def gmres_sharded(
     operator type (``shard_specs``) and the per-shard communication
     pattern comes from the operator's own shard-aware mat-vec (all-gather
     for dense, ppermute halo exchange for banded/ELL).  ``b`` is global
-    (n,).  Returns a replicated ``GmresResult``.
+    (n,).  Returns a ``GmresResult`` whose x is row-sharded over ``axis``
+    and whose scalars are replicated.
 
     The default ``gs="cgs2_fused"`` runs the split-phase CGS2 kernel pair
     per shard (project kernel, h psum, update kernel); it degrades to the

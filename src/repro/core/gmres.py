@@ -125,6 +125,26 @@ class GmresResult(NamedTuple):
             else self.diagnostics.residual_history
 
 
+def f32_matmuls(solver: Callable) -> Callable:
+    """Trace ``solver`` with every matmul at full f32 precision.
+
+    On TPU an f32 matmul at the default precision feeds the MXU bf16
+    operands, in XLA and in Pallas kernels alike; a Krylov solve then
+    stalls near bf16's epsilon (a dense N = 10,000 GMRES plateaued at a
+    4e-3 relative residual on a v5e chip).  The precision is fixed when a
+    dot is traced, so the solver entry points trace their bodies inside
+    ``jax.default_matmul_precision("highest")``.  CPU matmuls are f32
+    either way.
+    """
+
+    @functools.wraps(solver)
+    def traced_at_f32(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return solver(*args, **kwargs)
+
+    return traced_at_f32
+
+
 class _CycleState(NamedTuple):
     v: jax.Array             # (m+1, n_local) Krylov basis, row-major
     giv: givens.GivensState
@@ -388,6 +408,7 @@ def _gmres_cycle_pipelined(op, update, x0, r0, beta, m, tol_abs, precond,
     return x, state.steps
 
 
+@f32_matmuls
 def gmres(
     a,
     b: jax.Array,
@@ -622,6 +643,13 @@ def _block_cycle(blockmv, vprecond, batched_gs, x0, r0, beta, m, tol_abs,
     return x, steps
 
 
+def _lane_norms(r: jax.Array) -> jax.Array:
+    """Per-lane ``arnoldi.norm``: the same reduction ``gmres`` applies to
+    one vector, so a lane's residuals round exactly like a standalone
+    solve's (``jnp.linalg.norm(axis=1)`` differs in the last ulp)."""
+    return jax.vmap(arnoldi.norm)(r)
+
+
 def _block_matvec(op) -> Callable:
     """(k, n) -> (k, n) block mat-vec: ONE matrix stream for all k lanes.
 
@@ -668,6 +696,7 @@ def _batched_precond(precond) -> Callable:
     return jax.vmap(precond)
 
 
+@f32_matmuls
 def gmres_batched_cycle(a, b: jax.Array, x: jax.Array, *, m: int = 30,
                         tol_abs=None, active=None, gs: str = "cgs2",
                         precond: Optional[Callable] = None,
@@ -710,15 +739,16 @@ def gmres_batched_cycle(a, b: jax.Array, x: jax.Array, *, m: int = 30,
         active = jnp.ones(b.shape[:1], bool)
 
     r = b - blockmv(x)
-    beta = jnp.linalg.norm(r, axis=1)
+    beta = _lane_norms(r)
     act = active & (beta > tol_abs)
     x2, inner = _block_cycle(blockmv, vprecond, batched_gs, x, r, beta,
                              m, tol_abs, act, basis_dtype)
     x = jnp.where(act[:, None], x2, x)
-    beta = jnp.linalg.norm(b - blockmv(x), axis=1)
+    beta = _lane_norms(b - blockmv(x))
     return x, beta, inner
 
 
+@f32_matmuls
 def gmres_batched(a, b: jax.Array, *, m: int = 30, tol=1e-5,
                   max_restarts=50, gs: str = "cgs2",
                   precond: Optional[Callable] = None,
@@ -764,7 +794,7 @@ def gmres_batched(a, b: jax.Array, *, m: int = 30, tol=1e-5,
     batched_gs = _make_batched_gs(gs, m, b.shape[1], basis_dtype)
     blockmv = _block_matvec(op)
 
-    bnorm = jnp.linalg.norm(b, axis=1)
+    bnorm = _lane_norms(b)
     # tol / max_restarts broadcast: scalar or per-lane (batch,) arrays.
     tol_abs = jnp.maximum(jnp.asarray(tol, b.dtype) * bnorm,
                           jnp.asarray(0.0, b.dtype))
@@ -772,7 +802,7 @@ def gmres_batched(a, b: jax.Array, *, m: int = 30, tol=1e-5,
 
     def resid_of(x):
         r = b - blockmv(x)
-        return r, jnp.linalg.norm(r, axis=1)
+        return r, _lane_norms(r)
 
     x0 = jnp.zeros_like(b)
     r0, beta0 = resid_of(x0)

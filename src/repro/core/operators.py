@@ -140,10 +140,11 @@ class SparseOperator:
       "jnp"    — gather-and-reduce reference (XLA-lowered; always available)
       "pallas" — the row-blocked gather kernel (kernels/spmv.py) with the
                  operand x held VMEM-resident; block size from
-                 ``tuning.choose_spmv_block``.  On CPU the kernel runs in
-                 interpret mode; on backends without Pallas support, or
-                 when x does not fit VMEM (``tuning.spmv_fits``), the call
-                 silently degrades to the jnp path.
+                 ``tuning.choose_spmv_block``.  The kernel runs in the
+                 Pallas interpreter on CPU only: Mosaic lowers no in-kernel
+                 gather, so on the chip (``tuning.gather_kernel_mode``),
+                 or when x does not fit VMEM (``tuning.spmv_fits``), the
+                 call takes the jnp path.
 
     ``__call__`` accepts (n,) vectors or (n, k) multi-RHS blocks (one
     stream of the matrix feeds all k lanes — ``gmres_batched`` rides
@@ -174,7 +175,7 @@ class SparseOperator:
         if axis is not None:
             return self._sharded_call(v, axis, n, width, k)
         if self.backend == "pallas":
-            mode = tuning.kernel_mode()
+            mode = tuning.gather_kernel_mode()
             if mode != "ref" and tuning.spmv_fits(n, width,
                                                   self.values.dtype, k=k):
                 bm = tuning.choose_spmv_block(
@@ -210,7 +211,7 @@ class SparseOperator:
         cols_local = jnp.clip(self.cols - p * n + halo, 0,
                               n + 2 * halo - 1).astype(jnp.int32)
         x_halo = spmv.halo_exchange(v, halo, axis, tuning.shard_size())
-        mode = tuning.kernel_mode()
+        mode = tuning.gather_kernel_mode()
         if (self.backend == "pallas" and mode != "ref"
                 and tuning.spmv_fits(n, width, self.values.dtype, k=k,
                                      halo=halo)):
@@ -293,10 +294,10 @@ class BandedOperator:
 
       "jnp"    — shifted-window reference (XLA-lowered; always available)
       "pallas" — the stencil kernel (kernels/spmv.py): pure VPU work over
-                 dynamic slices of a halo-padded VMEM-resident x, no
-                 gather.  Interpret mode on CPU; silent degrade to jnp
-                 where Pallas is unavailable or the halo-padded operand
-                 exceeds VMEM (``tuning.banded_fits``).
+                 shifted windows of a lane-dense VMEM-resident x, no
+                 gather.  Interpret mode on CPU; the jnp path where Pallas
+                 is unavailable or the padded operand exceeds VMEM
+                 (``tuning.banded_fits``).
 
     Accepts (n,) or (n, k) operands; dtype semantics match dense ``a @ v``
     (promoted dtype out, f32 accumulation inside).
@@ -473,7 +474,7 @@ class SlicedEllOperator:
 
         n = self.perm.shape[0]
         if self.backend == "pallas":
-            mode = tuning.kernel_mode()
+            mode = tuning.gather_kernel_mode()
             if mode != "ref" and tuning.sell_fits(n, self.max_width,
                                                   self.dtype, k=k):
                 bms = tuple(
@@ -517,7 +518,7 @@ class SlicedEllOperator:
             cols_local = jnp.clip(cols_l - p * nl + halo, 0,
                                   nl + 2 * halo - 1).astype(jnp.int32)
             x_halo = spmv.halo_exchange(v, halo, axis, tuning.shard_size())
-            mode = tuning.kernel_mode()
+            mode = tuning.gather_kernel_mode()
             if (self.backend == "pallas" and mode != "ref"
                     and tuning.spmv_fits(nl, width, self.dtype, k=k,
                                          halo=halo)):

@@ -603,7 +603,7 @@ class BandedILU0Preconditioner(Preconditioner):
         return self._sweeps(v)
 
     def batched(self, vs):
-        # The scan-based reference sweeps vectorize over lanes directly.
+        # The jnp reference sweeps vectorize over lanes directly.
         from repro.kernels import trisolve
         sweep = jax.vmap(lambda v: trisolve.banded_trisweep_ref(
             self.l_bands, v, self.l_offsets, unit_diag=True, lower=True))

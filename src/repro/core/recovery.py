@@ -66,17 +66,16 @@ def build_ladder(gs: str = "cgs2_pipelined",
 
     Schemes step down within the current kernel mode first (cheap — same
     executables family, one retrace); when they are exhausted the kernel
-    mode drops one level (compiled -> interpret -> ref) and the scheme
-    ladder restarts from the top.  The final rung is always ("mgs", "ref")
-    — plain jnp modified Gram-Schmidt, no kernels anywhere.
+    mode drops to "ref" and the scheme ladder restarts from the top.  A
+    compiled solve never steps into the Pallas interpreter, which would
+    run on the chip at a fraction of its speed.  The final rung is always
+    ("mgs", "ref") — plain jnp modified Gram-Schmidt, no kernels anywhere.
     """
     mode = tuning.kernel_mode() if mode is None else mode
-    if mode not in tuning.KERNEL_MODE_LADDER:
+    if mode not in tuning.KERNEL_MODES:
         raise ValueError(f"unknown kernel mode {mode!r}")
     rungs: List[Tuple[str, str]] = []
-    for j, md in enumerate(
-            tuning.KERNEL_MODE_LADDER[
-                tuning.KERNEL_MODE_LADDER.index(mode):]):
+    for j, md in enumerate((mode,) if mode == "ref" else (mode, "ref")):
         if j == 0 and gs in DEGRADATION_SCHEMES:
             schemes = DEGRADATION_SCHEMES[DEGRADATION_SCHEMES.index(gs):]
         elif j == 0:
@@ -264,19 +263,20 @@ def gmres_self_healing(
             report.resumed_from = cycle
             report.log(cycle, "resume", rung, f"step {step}")
 
-    # One jitted single-cycle solver per visited rung, traced under that
+    # One compiled single-cycle solver per visited rung, traced under that
     # rung's forced kernel mode.  Each call IS one restart cycle of the
     # fused solver (pure in x), so committed trajectories are identical.
     cycle_fns = {}
 
-    def run_cycle(r, xc):
+    def cycle_fn(r):
         scheme, mode = ladder[r]
         if r not in cycle_fns:
-            cycle_fns[r] = jax.jit(lambda xx: gmres(
-                op, b, xx, m=m, tol=tol, max_restarts=1, gs=scheme,
-                precond=precond, compute_dtype=compute_dtype))
-        with tuning.force_kernel_mode(mode):
-            return cycle_fns[r](xc)
+            with tuning.force_kernel_mode(mode):
+                cycle_fns[r] = jax.jit(lambda xx: gmres(
+                    op, b, xx, m=m, tol=tol, max_restarts=1, gs=scheme,
+                    precond=precond, compute_dtype=compute_dtype)
+                ).lower(x).compile()
+        return cycle_fns[r]
 
     def step_down() -> bool:
         nonlocal rung
@@ -293,14 +293,17 @@ def gmres_self_healing(
     inner_steps = 0
     beta = hist[-1]
     while beta > tol_abs and cycle < max_restarts and not report.gave_up:
+        # Lowered and compiled outside the fault handler: a kernel the
+        # compiler refuses is a program error, not a transient fault.
+        fn = cycle_fn(rung)
         try:
             faultinject.check("core.cycle", index=cycle)
-            res = run_cycle(rung, x)
+            res = fn(x)
             x_new = res.x
             beta_new = float(res.residual)
             if faultinject.fire("core.cycle_nan", index=cycle):
                 beta_new = float("nan")
-        except Exception as e:  # noqa: BLE001 — every kernel fault lands here
+        except Exception as e:  # noqa: BLE001 — every execution fault lands here
             report.faults += 1
             report.log(cycle, "fault", rung, f"{type(e).__name__}: {e}")
             if retries < max_retries:

@@ -81,7 +81,7 @@ from jax import lax
 
 from repro.core import arnoldi, givens
 from repro.core.gmres import (Diagnostics, GmresResult, check_precond,
-                              classify_residuals)
+                              classify_residuals, f32_matmuls)
 from repro.core.operators import (EXPLICIT_OPERATORS, BandedOperator,
                                   DenseOperator, SparseOperator, as_operator,
                                   with_dtype)
@@ -171,7 +171,8 @@ def _make_block_fns(op, n: int, s: int, m1: int, dtype, axis_name,
                     interpret=interp)
         elif isinstance(op, SparseOperator):
             width = op.values.shape[1]
-            if tuning.ell_powers_fits(n, width, op.values.dtype, s):
+            if (tuning.gather_kernel_mode() != "ref"
+                    and tuning.ell_powers_fits(n, width, op.values.dtype, s)):
                 powers_fn = lambda u0: matrix_powers.ell_powers(
                     op.values, op.cols, u0, s, shifts=shifts,
                     interpret=interp)
@@ -391,6 +392,7 @@ def _block_step(powers_fn, gs_pass, v_basis, h, k_start: int, s: int, eps,
     return v_basis, h, gram
 
 
+@f32_matmuls
 def gmres_sstep(a, b, x0=None, *, s: int = 4, blocks: int = 5,
                 tol: float = 1e-5, max_restarts: int = 30,
                 axis_name: Optional[str] = None,

@@ -19,15 +19,16 @@ the projection and the update.  This kernel runs the ENTIRE step in one
     phase 1 — one grid step: both CGS2 passes against the basis V held
               ENTIRELY in VMEM (a (m+1, n) f32 basis is ~m*n*4 bytes —
               128 KiB per 1k of n at m=30 — far under the ~16 MiB core
-              budget for every problem the tuner admits).  ``h`` and the
-              intermediate ``w'`` never exist in HBM at all.
+              budget for every problem the tuner admits), as f32 VPU
+              products.  ``h`` and the intermediate ``w'`` never exist in
+              HBM at all.
 
 HBM traffic per step: A once, V once, v_j once in; h + w'' once out.  The
 unfused kernel pair streams V four times and round-trips w three times —
 ``benchmarks/kernel_bench.py`` carries the model.  A streams in whatever
 dtype it arrives in and upcasts in-register: the solver exploits this for
 ``compute_dtype=bf16`` by downcasting the padded A ONCE per solve
-(core/gmres.py), halving the dominant HBM term while the dot_generals
+(core/gmres.py), halving the dominant HBM term while the products
 still accumulate at f32/f64.
 
 Feasibility (V must fit in VMEM) is decided by ``tuning.fused_step_fits``;
@@ -47,11 +48,6 @@ from jax.experimental import pallas as pl
 from repro.kernels import tuning
 
 
-def _dot(a, b, dims, acc):
-    return jax.lax.dot_general(a, b, dimension_numbers=(dims, ((), ())),
-                               preferred_element_type=acc)
-
-
 def _fused_kernel(a_ref, vj_ref, vb_ref, mask_ref, h_ref, w_ref, *, bm, nbi):
     i = pl.program_id(0)
     j = pl.program_id(1)
@@ -64,23 +60,27 @@ def _fused_kernel(a_ref, vj_ref, vb_ref, mask_ref, h_ref, w_ref, *, bm, nbi):
 
     @pl.when(i < nbi)
     def _matvec():
-        # (bm, bn) @ (bn, 1) -> (bm, 1) partial of w, accumulated into the
-        # VMEM-resident slice of the full w buffer.
-        w_ref[pl.ds(i * bm, bm), :] += _dot(a_ref[...], vj_ref[...],
-                                            (((1,), (0,))), acc)
+        # (1, bn) x (bm, bn)^T -> (1, bm) partial of the lane-dense w row,
+        # accumulated into its VMEM-resident slice.
+        w_ref[:, pl.ds(pl.multiple_of(i * bm, bm), bm)] += (
+            jax.lax.dot_general(vj_ref[...], a_ref[...],
+                                dimension_numbers=(((1,), (1,)), ((), ())),
+                                preferred_element_type=acc))
 
     @pl.when((i == nbi) & (j == 0))
     def _orthogonalize():
-        # Both CGS2 passes on the VMEM-resident basis; pure MXU work, no
-        # HBM traffic.  The basis is upcast in-register so bf16 storage
-        # still accumulates in full precision.
-        v = vb_ref[...].astype(acc)               # (m1, n)
-        mask = mask_ref[...]                      # (m1, 1)
-        w = w_ref[...]                            # (n, 1) acc dtype
-        h1 = mask * _dot(v, w, (((1,), (0,))), acc)    # project
-        w1 = w - _dot(v, h1, (((0,), (0,))), acc)      # update: w - V^T h1
-        h2 = mask * _dot(v, w1, (((1,), (0,))), acc)   # reorthogonalize
-        w2 = w1 - _dot(v, h2, (((0,), (0,))), acc)
+        # Both CGS2 passes on the VMEM-resident basis, as exact f32 VPU
+        # products and reductions: an f32 MXU contraction over n would
+        # need multi-pass temporaries the size of the basis.  The basis
+        # is upcast in-register so bf16 storage still accumulates in full
+        # precision.
+        v = vb_ref[...].astype(acc)                         # (m1, n)
+        mask = mask_ref[...]                                # (m1, 1)
+        w = w_ref[...]                                      # (1, n)
+        h1 = mask * jnp.sum(v * w, axis=1, keepdims=True)   # project
+        w1 = w - jnp.sum(v * h1, axis=0, keepdims=True)     # w - V^T h1
+        h2 = mask * jnp.sum(v * w1, axis=1, keepdims=True)  # reorthogonalize
+        w2 = w1 - jnp.sum(v * h2, axis=0, keepdims=True)
         h_ref[...] = h1 + h2
         w_ref[...] = w2                           # overwrite the accumulator
 
@@ -130,23 +130,25 @@ def arnoldi_step(a: jax.Array, v_basis: jax.Array, j, *,
             pl.BlockSpec((b, b), lambda i, j: (jnp.minimum(i, nbi - 1),
                                                jnp.where(i < nbi, j,
                                                          nbi - 1))),
-            pl.BlockSpec((b, 1), lambda i, j: (jnp.where(i < nbi, j, 0), 0)),
+            pl.BlockSpec((1, b), lambda i, j: (0, jnp.where(i < nbi, j, 0))),
             # The whole basis is ONE block: fetched once, VMEM-resident.
             pl.BlockSpec((m1_pad, n_pad), lambda i, j: (0, 0)),
             pl.BlockSpec((m1_pad, 1), lambda i, j: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((m1_pad, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((n_pad, 1), lambda i, j: (0, 0)),
+            # w as a lane-dense row: an (n, 1) column would pad each
+            # element to a 128-lane row of VMEM.
+            pl.BlockSpec((1, n_pad), lambda i, j: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m1_pad, 1), acc_dtype),
-            jax.ShapeDtypeStruct((n_pad, 1), acc_dtype),
+            jax.ShapeDtypeStruct((1, n_pad), acc_dtype),
         ],
         interpret=interpret,
         name="gmres_arnoldi_fused",
-    )(a, vj[:, None], v_basis, mask[:, None].astype(acc_dtype))
-    return h[:m1, 0], w[:n, 0]
+    )(a, vj[None, :], v_basis, mask[:, None].astype(acc_dtype))
+    return h[:m1, 0], w[0, :n]
 
 
 def arnoldi_step_ref(a: jax.Array, v_basis: jax.Array, j):

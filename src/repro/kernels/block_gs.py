@@ -421,16 +421,16 @@ def block_gs_pass_single_reduce_ref(v: jax.Array, w: jax.Array,
 def _batched_cgs2_kernel(v_ref, w_ref, mask_ref, h_ref, wout_ref):
     acc = h_ref.dtype
     v = v_ref[0].astype(acc)                          # (m1p, np) this lane
-    w = w_ref[...]                                    # (1, np)
-    mask = mask_ref[...]                              # (1, m1p)
+    w = w_ref[0]                                      # (1, np)
+    mask = mask_ref[0]                                # (1, m1p)
     # Both CGS2 passes against the VMEM-resident lane basis; h and the
     # intermediate w' never exist in HBM.
     h1 = mask * _dot(w, v, ((1,), (1,)), acc)         # (1, m1p) project
     w1 = w - _dot(h1, v, ((1,), (0,)), acc)           # (1, np)   update
     h2 = mask * _dot(w1, v, ((1,), (1,)), acc)        # reorthogonalize
     w2 = w1 - _dot(h2, v, ((1,), (0,)), acc)
-    h_ref[...] = h1 + h2
-    wout_ref[...] = w2
+    h_ref[0] = h1 + h2
+    wout_ref[0] = w2
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -449,27 +449,30 @@ def batched_cgs2(v: jax.Array, w: jax.Array, mask: jax.Array, *,
                         f"mask ({k}, {m1}); got {w.shape}, {mask.shape}")
     acc = jnp.promote_types(w.dtype, jnp.float32)
     m1p, np_, _ = tuning.choose_block_gs(m1, n, 1, jnp.dtype(v.dtype).name)
+    # Per-lane rows travel as (k, 1, len) so each block's last two dims
+    # equal the array's — Mosaic tiles a (1, len) block of a (k, len)
+    # array only when k rows would fill the sublane tile.
     v = jnp.pad(v, ((0, 0), (0, m1p - m1), (0, np_ - n)))
-    w = jnp.pad(w.astype(acc), ((0, 0), (0, np_ - n)))
-    mask = jnp.pad(mask.astype(acc), ((0, 0), (0, m1p - m1)))
+    w = jnp.pad(w.astype(acc), ((0, 0), (0, np_ - n)))[:, None, :]
+    mask = jnp.pad(mask.astype(acc), ((0, 0), (0, m1p - m1)))[:, None, :]
 
     h, w2 = pl.pallas_call(
         _batched_cgs2_kernel,
         grid=(k,),
         in_specs=[
             pl.BlockSpec((1, m1p, np_), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, np_), lambda i: (i, 0)),
-            pl.BlockSpec((1, m1p), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, np_), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, m1p), lambda i: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, m1p), lambda i: (i, 0)),
-            pl.BlockSpec((1, np_), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, m1p), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, np_), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((k, m1p), acc),
-            jax.ShapeDtypeStruct((k, np_), acc),
+            jax.ShapeDtypeStruct((k, 1, m1p), acc),
+            jax.ShapeDtypeStruct((k, 1, np_), acc),
         ],
         interpret=interpret,
         name="gmres_block_gs_batched",
     )(v, w, mask)
-    return h[:, :m1], w2[:, :n]
+    return h[:, 0, :m1], w2[:, 0, :n]

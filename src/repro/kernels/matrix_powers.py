@@ -91,6 +91,20 @@ def _acc_dtype(mat_dtype, x_dtype):
                              jnp.float32)
 
 
+def _lane_put(row, p, value):
+    """``row`` (1, L) with lane ``p`` set to ``value``.  Mosaic stores no
+    scalar to VMEM and no dynamic lane slice, so the write is a masked
+    select over the whole row."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    return jnp.where(lane == p, value, row)
+
+
+def _lane_pick(row, p):
+    """Lane ``p`` of ``row`` (1, L) as a (1, 1) value (see ``_lane_put``)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    return jnp.sum(jnp.where(lane == p, row, 0), axis=1, keepdims=True)
+
+
 # --------------------------------------------------------------------------
 # Banded / stencil matrix powers
 # --------------------------------------------------------------------------
@@ -117,12 +131,12 @@ def _banded_powers_kernel(bands_ref, x_ref, sh_ref, u_ref, sig_ref,
     if shifted:
         # Newton basis: w = (A - shift_j I) u_{j-1} — same one-pass stream,
         # the shift is a per-power scalar from the tiny shifts block.
-        sh = pl.load(sh_ref, (pl.ds(0, 1), pl.ds(p, 1)))
+        sh = _lane_pick(sh_ref[...], p)
         w -= sh * pad_ref[:, pl.ds(halo, n_pad)]
 
     sigma = jnp.sqrt(jnp.sum(w * w))
     u = w / jnp.maximum(sigma, eps)
-    sig_ref[0, p] = sigma
+    sig_ref[...] = _lane_put(sig_ref[...], p, sigma)
     u_ref[pl.ds(p, 1), :] = u
     pad_ref[:, pl.ds(halo, n_pad)] = u     # operand for the next power
 
@@ -213,7 +227,8 @@ def _banded_powers_halo_kernel(bands_ref, x_ref, z_ref, nrm_ref, pad_ref, *,
         w += band * pad_ref[:, pl.ds(halo + off, w_width)]
 
     zc = w[:, center:center + ln]            # this shard's rows of z_{p+1}
-    nrm_ref[0, p] = jnp.sum(zc * zc)         # PER-SHARD partial sq-norm
+    nrm_ref[...] = _lane_put(nrm_ref[...], p,       # PER-SHARD partial
+                             jnp.sum(zc * zc))      # sq-norm
     z_ref[pl.ds(p, 1), :] = zc
     pad_ref[:, pl.ds(halo, w_width)] = w     # raw carry — no division here
 
@@ -291,7 +306,7 @@ def _dense_powers_kernel(a_ref, x_ref, u_ref, sig_ref, cur_ref, w_ref, *,
     def _normalize(power):
         w = w_ref[...]
         sigma = jnp.sqrt(jnp.sum(w * w))
-        sig_ref[0, power] = sigma
+        sig_ref[...] = _lane_put(sig_ref[...], power, sigma)
         u = w / jnp.maximum(sigma, eps)
         u_ref[pl.ds(power, 1), :] = u
         return u
@@ -394,12 +409,12 @@ def _ell_powers_kernel(vals_ref, cols_ref, x_ref, sh_ref, u_ref, sig_ref,
     g = jnp.take(cur_ref[0, :], cols_ref[...], axis=0).astype(acc)
     w = jnp.sum(vals_ref[...].astype(acc) * g, axis=1)[None, :]
     if shifted:
-        sh = pl.load(sh_ref, (pl.ds(0, 1), pl.ds(p, 1)))
+        sh = _lane_pick(sh_ref[...], p)
         w -= sh * cur_ref[...]
 
     sigma = jnp.sqrt(jnp.sum(w * w))
     u = w / jnp.maximum(sigma, eps)
-    sig_ref[0, p] = sigma
+    sig_ref[...] = _lane_put(sig_ref[...], p, sigma)
     u_ref[pl.ds(p, 1), :] = u
     cur_ref[...] = u                         # operand for the next power
 

@@ -72,6 +72,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import tuning
+from repro.kernels.tuning import LANE, _round_up
 
 
 def _acc_dtypes(mat_dtype, x_dtype):
@@ -227,15 +231,67 @@ def sell_matvec_ref(bin_values: tuple, bin_cols: tuple,
 # --------------------------------------------------------------------------
 # Banded / stencil kernel
 # --------------------------------------------------------------------------
-def _banded_kernel(bt_ref, x_ref, o_ref, *, offsets, halo, bm):
+# Lane-dense layout: a length-n vector travels as (n_pad / 128, 128) rows,
+# so the VMEM-resident operand costs 4 bytes per row instead of the 512 a
+# (n, 1) column pays once Mosaic pads its lane dim to 128.  A band offset
+# ``off`` then splits into a static sublane shift ``q`` and lane shift
+# ``sh`` (``divmod(front + off, 128)``): each shifted window is two
+# sublane-offset loads of the resident operand joined by a lane rotation.
+def _banded_kernel(bt_ref, x_ref, o_ref, *, offsets, front, br):
     i = pl.program_id(0)
-    base = i * bm + halo                     # row 0 of this tile, in x_pad
-    acc = jnp.zeros(o_ref.shape, o_ref.dtype)
-    for d, off in enumerate(offsets):        # static unroll over the bands
-        seg = x_ref[pl.ds(base + off, bm), :]            # (bm, k) window
-        band = bt_ref[:, d:d + 1]                        # (bm, 1)
-        acc += band.astype(o_ref.dtype) * seg.astype(o_ref.dtype)
-    o_ref[...] = acc
+    row0 = pl.multiple_of(i * br, br)        # first 128-wide row of tile
+    lane = lax.broadcasted_iota(jnp.int32, (br, LANE), 1)
+    for c in range(x_ref.shape[0]):          # static unroll over the k RHS
+        acc = jnp.zeros((br, LANE), o_ref.dtype)
+        for d, off in enumerate(offsets):    # static unroll over the bands
+            q, sh = divmod(front + off, LANE)
+            win = x_ref[c, pl.ds(row0 + q, br), :]
+            if sh:
+                # win[r, l] = x[row0+q+r, l+sh] while l+sh < 128, else the
+                # next row's x[row0+q+r+1, l+sh-128]: rotate both rows left
+                # by sh and take each lane from the row that holds it.
+                nxt = x_ref[c, pl.ds(row0 + q + 1, br), :]
+                win = jnp.where(lane < LANE - sh,
+                                pltpu.roll(win, LANE - sh, 1),
+                                pltpu.roll(nxt, LANE - sh, 1))
+            acc += bt_ref[d].astype(o_ref.dtype) * win.astype(o_ref.dtype)
+        o_ref[c] = acc
+
+
+def _banded_pallas(bands, x_rows, offsets, lead, block_m, interpret,
+                   acc_dtype, compute_dtype):
+    """Shared pallas_call.  bands: (nbands, n); x_rows: (rows, k) holding
+    local row i at ``lead + i`` (lead = 0 single-device, where rows past
+    either end are zero; lead = halo for the exchanged halo operand).  The
+    operand stays WHOLE in VMEM; returns the (n, k) product."""
+    nbands, n = bands.shape
+    k = x_rows.shape[1]
+    halo = max(abs(int(o)) for o in offsets)
+    sub = tuning.sublane(bands.dtype)
+    br = _round_up(max(1, -(-block_m // LANE)), sub)
+    br = min(br, _round_up(-(-n // LANE), sub))
+    n_pad = _round_up(n, br * LANE)
+    front = _round_up(halo, LANE)
+    rows = _round_up(-(-(front + n_pad + halo) // LANE) + 1, 8)
+    xf = jnp.pad(x_rows.T.astype(acc_dtype),
+                 ((0, 0), (front - lead,
+                           rows * LANE - front + lead - x_rows.shape[0])))
+    bt = jnp.pad(bands, ((0, 0), (0, n_pad - n)))
+    out = pl.pallas_call(
+        functools.partial(_banded_kernel, offsets=offsets, front=front,
+                          br=br),
+        grid=(n_pad // (br * LANE),),
+        in_specs=[
+            pl.BlockSpec((nbands, br, LANE), lambda i: (0, i, 0)),
+            pl.BlockSpec((k, rows, LANE), lambda i: (0, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((k, br, LANE), lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((k, n_pad // LANE, LANE), acc_dtype),
+        interpret=interpret,
+        name="gmres_spmv_banded",
+    )(bt.reshape(nbands, n_pad // LANE, LANE),
+      xf.reshape(k, rows, LANE))
+    return out.reshape(k, n_pad)[:, :n].T.astype(compute_dtype)
 
 
 @functools.partial(jax.jit,
@@ -246,8 +302,10 @@ def banded_matvec(bands: jax.Array, x: jax.Array, offsets: tuple, *,
 
     bands: (nbands, n); offsets: static tuple of diagonal shifts (one per
     band, e.g. (-nx, -1, 0, 1, nx) for the five-point stencil); x: (n,) or
-    (n, k).  x is halo-padded with zeros so every shifted window is a plain
-    dynamic slice — no gather, no per-band bounds check.
+    (n, k).  ``block_m`` is rows per grid step, rounded up to whole
+    (sublane x 128) tiles.  x is zero-padded around its resident copy so
+    every shifted window is a plain slice — no gather, no per-band bounds
+    check.
     """
     nbands, n = bands.shape
     if len(offsets) != nbands:
@@ -257,46 +315,10 @@ def banded_matvec(bands: jax.Array, x: jax.Array, offsets: tuple, *,
         raise TypeError(f"banded_matvec: bands {bands.shape} @ x {x.shape} — "
                         f"x must have {n} rows")
     squeeze = x.ndim == 1
-    if squeeze:
-        x = x[:, None]
-    k = x.shape[1]
-    bm = min(block_m, n)
-    if n % bm:
-        np_ = (n + bm - 1) // bm * bm
-        out = banded_matvec(
-            jnp.pad(bands, ((0, 0), (0, np_ - n))),
-            jnp.pad(x, ((0, np_ - n), (0, 0))),
-            offsets, block_m=bm, interpret=interpret)[:n]
-        return out[:, 0] if squeeze else out
-
-    halo = max(abs(int(o)) for o in offsets)
     compute_dtype, acc_dtype = _acc_dtypes(bands.dtype, x.dtype)
-    x_pad = jnp.pad(x.astype(compute_dtype), ((halo, halo), (0, 0)))
-    out = _banded_pallas(bands, x_pad, offsets, halo, bm, interpret,
-                         acc_dtype).astype(compute_dtype)
+    out = _banded_pallas(bands, x[:, None] if squeeze else x, offsets, 0,
+                         block_m, interpret, acc_dtype, compute_dtype)
     return out[:, 0] if squeeze else out
-
-
-def _banded_pallas(bands, x_pad, offsets, halo, bm, interpret, acc_dtype):
-    """Shared pallas_call: bands (nbands, n), x_pad (n + 2*halo, k) — the
-    operand arrives halo-padded (zeros single-device, neighbor rows when
-    row-sharded) and stays WHOLE in VMEM."""
-    nbands, n = bands.shape
-    k = x_pad.shape[1]
-    return pl.pallas_call(
-        functools.partial(_banded_kernel, offsets=offsets, halo=halo, bm=bm),
-        grid=(n // bm,),
-        in_specs=[
-            # bands transposed to (n, nbands): the per-tile read is then a
-            # contiguous (bm, nbands) block and each band is a column slice.
-            pl.BlockSpec((bm, nbands), lambda i: (i, 0)),
-            pl.BlockSpec((n + 2 * halo, k), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, k), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, k), acc_dtype),
-        interpret=interpret,
-        name="gmres_spmv_banded",
-    )(bands.T, x_pad)
 
 
 def banded_matvec_ref(bands: jax.Array, x: jax.Array,
@@ -369,23 +391,10 @@ def banded_matvec_halo(bands: jax.Array, x_halo: jax.Array, offsets: tuple,
                         f"halo={halo} need x_halo of {n + 2 * halo} rows, "
                         f"got {x_halo.shape}")
     squeeze = x_halo.ndim == 1
-    if squeeze:
-        x_halo = x_halo[:, None]
-    bm = min(block_m, n)
-    if n % bm:
-        # Pad the row grid; appended zero-band rows read (real) trailing
-        # halo values times zero, so they contribute nothing, and every
-        # live row's read indices are unchanged.
-        np_ = (n + bm - 1) // bm * bm
-        out = banded_matvec_halo(
-            jnp.pad(bands, ((0, 0), (0, np_ - n))),
-            jnp.pad(x_halo, ((0, np_ - n), (0, 0))),
-            offsets, block_m=bm, interpret=interpret)[:n]
-        return out[:, 0] if squeeze else out
-
     compute_dtype, acc_dtype = _acc_dtypes(bands.dtype, x_halo.dtype)
-    out = _banded_pallas(bands, x_halo.astype(compute_dtype), offsets, halo,
-                         bm, interpret, acc_dtype).astype(compute_dtype)
+    out = _banded_pallas(bands, x_halo[:, None] if squeeze else x_halo,
+                         offsets, halo, block_m, interpret, acc_dtype,
+                         compute_dtype)
     return out[:, 0] if squeeze else out
 
 

@@ -23,7 +23,7 @@ its ``line_jacobi`` / ``banded_block_jacobi`` restrictions).  Two pieces:
   kernel walks sequential row blocks on a (nb,) grid with the trailing K
   solved entries carried in a VMEM scratch ring — one HBM read of bands/v
   and one write of z — and ``banded_trisweep_ref`` is the psum-safe
-  ``lax.scan`` oracle (also the vmapped multi-RHS path: substitution is
+  blocked jnp oracle (also the vmapped multi-RHS path: substitution is
   sequential in rows but embarrassingly parallel across lanes).
 
   An UPPER solve is a lower solve read back-to-front: flip bands/v along
@@ -41,7 +41,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import tuning
-from repro.kernels.tuning import LANE, _round_up
+from repro.kernels.tuning import _round_up
 
 
 def _mask_oob(bands, offsets):
@@ -130,29 +130,57 @@ def _ilu0_factor(bands: jax.Array, offsets: tuple):
 
 
 # --------------------------------------------------------------------------
-# Triangular sweep: lax.scan reference
+# Triangular sweep: blocked jnp reference
 # --------------------------------------------------------------------------
 def _forward_ref(bands, v, offsets, unit_diag):
-    """Forward substitution; offsets all <= 0 (0 present iff not unit)."""
+    """Forward substitution; offsets all <= 0 (0 present iff not unit).
+
+    Rows go in blocks of L = the smallest |offset| other than 1, so every
+    other offset reaches only rows of earlier blocks and a block's rows
+    couple to each other through offset -1 alone.  Per block the far
+    terms are one vector update and the -1 chain ``z_i = a_i z_(i-1) +
+    w_i`` one ``associative_scan`` of affine maps: n / L sequential steps
+    (the grid height for a 2-D stencil) instead of n, which is what makes
+    this path usable on the chip.
+    """
     acc = jnp.promote_types(bands.dtype if bands.size else v.dtype,
                             jnp.promote_types(v.dtype, jnp.float32))
-    k_ring = max((-o for o in offsets), default=0) or 1
-    idx0 = offsets.index(0) if 0 in offsets else None
+    n = v.shape[0]
+    idx = {off: d for d, off in enumerate(offsets)}
+    far = [o for o in offsets if o < -1]
+    k = max((-o for o in offsets), default=0)       # deepest reach back
+    blk = min(min((-o for o in far), default=n), n) or 1
+    nblk = -(-n // blk)
+    n_pad = nblk * blk
 
-    def step(ring, inp):
-        row, rhs = inp
-        z = rhs
-        for d, off in enumerate(offsets):
-            if off < 0:
-                z = z - row[d] * ring[k_ring + off]
-        if not unit_diag:
-            z = z / row[idx0]
-        ring = jnp.concatenate([ring[1:], z[None]])
-        return ring, z
+    def band(off, fill):
+        b = bands[idx[off]].astype(acc)
+        return jnp.pad(b, (0, n_pad - n), constant_values=fill)
 
-    seed = jnp.zeros((k_ring,), acc)
-    _, z = lax.scan(step, seed, (bands.T.astype(acc), v.astype(acc)))
-    return z.astype(jnp.promote_types(bands.dtype, v.dtype))
+    v_p = jnp.pad(v.astype(acc), (0, n_pad - n))
+    sub = band(-1, 0.0) if -1 in idx else jnp.zeros((n_pad,), acc)
+    diag = None if unit_diag else band(0, 1.0)
+    far_bands = [(off, band(off, 0.0)) for off in far]
+
+    def block(t, z):
+        # z holds row i at k + i; rows before the first stay zero.
+        start = t * blk
+        w = lax.dynamic_slice(v_p, (start,), (blk,))
+        for off, b in far_bands:
+            w = w - (lax.dynamic_slice(b, (start,), (blk,))
+                     * lax.dynamic_slice(z, (k + start + off,), (blk,)))
+        a = -lax.dynamic_slice(sub, (start,), (blk,))
+        if diag is not None:
+            d = lax.dynamic_slice(diag, (start,), (blk,))
+            a, w = a / d, w / d
+        # Compose z -> a_i z + w_i left to right: prefix map (p_i, q_i).
+        p, q = lax.associative_scan(
+            lambda e, l: (l[0] * e[0], l[0] * e[1] + l[1]), (a, w))
+        z_in = lax.dynamic_index_in_dim(z, k + start - 1, keepdims=False)
+        return lax.dynamic_update_slice(z, p * z_in + q, (k + start,))
+
+    z = lax.fori_loop(0, nblk, block, jnp.zeros((k + n_pad,), acc))
+    return z[k:k + n].astype(jnp.promote_types(bands.dtype, v.dtype))
 
 
 def banded_trisweep_ref(bands: jax.Array, v: jax.Array, offsets: tuple, *,
@@ -190,8 +218,12 @@ def _check_tri(bands, v, offsets, unit_diag, lower):
 # --------------------------------------------------------------------------
 def _trisweep_kernel(b_ref, v_ref, o_ref, zp_ref, *,
                      offsets, unit_diag, k_ring, bm):
-    """Sequential row blocks; zp_ref (1, k_ring + bm) carries the trailing
-    k_ring solved entries across blocks (requires bm >= k_ring)."""
+    """Sequential row blocks, one matrix row per SUBLANE: b_ref is the
+    (bm, nbands) tile, v_ref/o_ref (bm, 1), and zp_ref (k_ring + bm, 1)
+    carries the trailing k_ring solved entries across blocks (requires
+    bm >= k_ring).  Rows on sublanes make every per-row access a dynamic
+    sublane offset, which Mosaic lowers; a dynamic LANE offset it refuses.
+    """
     i = pl.program_id(0)
     acc = o_ref.dtype
     idx0 = offsets.index(0) if 0 in offsets else None
@@ -201,22 +233,20 @@ def _trisweep_kernel(b_ref, v_ref, o_ref, zp_ref, *,
         zp_ref[...] = jnp.zeros_like(zp_ref)
 
     def row(r, carry):
-        z = pl.load(v_ref, (pl.ds(0, 1), pl.ds(r, 1))).astype(acc)
+        z = v_ref[pl.ds(r, 1), :].astype(acc)
         for d, off in enumerate(offsets):
             if off < 0:
-                coef = pl.load(b_ref, (pl.ds(d, 1), pl.ds(r, 1))).astype(acc)
-                z = z - coef * pl.load(
-                    zp_ref, (pl.ds(0, 1), pl.ds(r + k_ring + off, 1)))
+                coef = b_ref[pl.ds(r, 1), pl.ds(d, 1)].astype(acc)
+                z = z - coef * zp_ref[pl.ds(r + k_ring + off, 1), :]
         if not unit_diag:
-            z = z / pl.load(b_ref,
-                            (pl.ds(idx0, 1), pl.ds(r, 1))).astype(acc)
-        pl.store(zp_ref, (pl.ds(0, 1), pl.ds(k_ring + r, 1)), z)
-        pl.store(o_ref, (pl.ds(0, 1), pl.ds(r, 1)), z)
+            z = z / b_ref[pl.ds(r, 1), pl.ds(idx0, 1)].astype(acc)
+        zp_ref[pl.ds(k_ring + r, 1), :] = z
+        o_ref[pl.ds(r, 1), :] = z
         return carry
 
     lax.fori_loop(0, bm, row, 0, unroll=False)
     # Shift the trailing solved entries to the front for the next block.
-    zp_ref[0, :k_ring] = zp_ref[0, bm:bm + k_ring]
+    zp_ref[:k_ring, :] = zp_ref[bm:bm + k_ring, :]
 
 
 @functools.partial(
@@ -242,9 +272,10 @@ def banded_trisweep_kernel(bands: jax.Array, v: jax.Array, offsets: tuple, *,
     out_dtype = jnp.promote_types(bands.dtype, v.dtype)
     acc = jnp.promote_types(out_dtype, jnp.float32)
     k_ring = max((-o for o in offsets), default=0) or 1
-    bm = block_m or tuning.choose_trisweep_block(n, len(offsets), k_ring)
-    bm = max(bm, _round_up(k_ring, LANE))          # carry shift needs bm>=K
+    bm = block_m or tuning.choose_trisweep_block(n, k_ring)
+    bm = max(_round_up(bm, 8), _round_up(k_ring, 8))   # carry needs bm>=K
     n_pad = _round_up(n, bm)
+    nb = max(len(offsets), 1)
 
     # Padded tail rows solve to v = 0: identity diagonal, zero off-diags.
     bands_p = jnp.pad(bands.astype(acc), ((0, 0), (0, n_pad - n)))
@@ -252,23 +283,25 @@ def banded_trisweep_kernel(bands: jax.Array, v: jax.Array, offsets: tuple, *,
         pad_diag = jnp.arange(n_pad) >= n
         d0 = offsets.index(0)
         bands_p = bands_p.at[d0].set(jnp.where(pad_diag, 1.0, bands_p[d0]))
-    v_p = jnp.pad(v.astype(acc), (0, n_pad - n))[None, :]
+    if not bands.size:
+        bands_p = jnp.zeros((1, n_pad), acc)
+    v_p = jnp.pad(v.astype(acc), (0, n_pad - n))[:, None]
 
     z = pl.pallas_call(
         functools.partial(_trisweep_kernel, offsets=offsets,
                           unit_diag=unit_diag, k_ring=k_ring, bm=bm),
         grid=(n_pad // bm,),
         in_specs=[
-            pl.BlockSpec((max(len(offsets), 1), bm), lambda i: (0, i)),
-            pl.BlockSpec((1, bm), lambda i: (0, i)),
+            pl.BlockSpec((bm, nb), lambda i: (i, 0)),
+            pl.BlockSpec((bm, 1), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bm), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, n_pad), acc),
-        scratch_shapes=[pltpu.VMEM((1, k_ring + bm), acc)],
+        out_specs=pl.BlockSpec((bm, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_pad, 1), acc),
+        scratch_shapes=[pltpu.VMEM((k_ring + bm, 1), acc)],
         interpret=interpret,
         name="gmres_precond_trisweep",
-    )(bands_p if bands.size else jnp.zeros((1, n_pad), acc), v_p)
-    return z[0, :n].astype(out_dtype)
+    )(bands_p.T, v_p)
+    return z[:n, 0].astype(out_dtype)
 
 
 def banded_trisweep(bands: jax.Array, v: jax.Array, offsets: tuple, *,

@@ -60,11 +60,26 @@ def _round_up(x: int, mult: int) -> int:
     return -(-x // mult) * mult
 
 
-# Kernel execution modes, fastest first.  ``KERNEL_MODE_LADDER`` is the
-# degradation order the recovery layer (core/recovery.py) steps down when a
-# kernel-backed cycle faults: compiled Pallas -> the (slow, bit-accurate)
-# interpreter -> the pure-jnp reference.
-KERNEL_MODE_LADDER = ("compiled", "interpret", "ref")
+def vmem_bytes(rows: int, cols: int, dtype) -> int:
+    """Bytes Mosaic allocates for a (rows, cols) VMEM buffer: the last dim
+    padded to 128 lanes and the one before it to the sublane tile.  A
+    32-bit buffer with fewer rows than the tile takes the next power of
+    two of them instead (the v5e compiler lays out f32 (1, n) as
+    T(1,128), (2, n) as T(2,128) and (5, n) as T(8,128)).  Every fits
+    gate below prices its kernel's buffers with this, so a (n, 1) column
+    costs 512 bytes per row and a (1, n) f32 row its payload."""
+    tile = sublane(dtype)
+    if itemsize(dtype) == 4 and rows < tile:
+        tile = 1 << (max(rows, 1) - 1).bit_length()
+    return (_round_up(max(rows, 1), tile)
+            * _round_up(max(cols, 1), LANE) * itemsize(dtype))
+
+
+# Kernel execution modes: compiled Pallas (TPU), the Pallas interpreter
+# (CPU: slow, bit-accurate) and the pure-jnp reference.  The recovery
+# layer (core/recovery.py) steps from the backend's own mode straight to
+# "ref" — never from "compiled" into the interpreter.
+KERNEL_MODES = ("compiled", "interpret", "ref")
 
 _FORCED_MODE: list = []   # stack; trace-time static, like shard_context
 
@@ -74,14 +89,14 @@ def force_kernel_mode(mode: str):
     """Pin ``kernel_mode()`` for code traced inside (trace-time static).
 
     This is the recovery ladder's kernel-stack rung control: re-tracing a
-    cycle under ``force_kernel_mode("interpret")`` / ``("ref")`` steps the
-    solve down to a slower-but-safer execution mode WITHOUT touching the
+    cycle under ``force_kernel_mode("ref")`` steps the solve down to the
+    kernel-free execution mode WITHOUT touching the
     ``REPRO_KERNELS`` environment (which stays the process-wide default).
     Takes precedence over the env override; nests like ``shard_context``.
     """
-    if mode not in KERNEL_MODE_LADDER:
+    if mode not in KERNEL_MODES:
         raise ValueError(f"unknown kernel mode {mode!r}; "
-                         f"options: {list(KERNEL_MODE_LADDER)}")
+                         f"options: {list(KERNEL_MODES)}")
     _FORCED_MODE.append(mode)
     try:
         yield
@@ -112,6 +127,21 @@ def kernel_mode() -> str:
     return "ref"  # GPU etc.: these kernels are TPU-shaped; use the reference
 
 
+# Kernels whose body gathers from a VMEM operand (``jnp.take``).  Mosaic
+# lowers no such gather ("Shape mismatch in input, indices and output",
+# "Only 2D gather is supported"), so they run in the interpreter only and
+# their dispatch takes the jnp reference on the chip.
+GATHER_KERNELS = ("ell_matvec", "ell_matvec_halo", "sell_matvec",
+                  "ell_powers")
+
+
+def gather_kernel_mode() -> str:
+    """``kernel_mode()`` for the ``GATHER_KERNELS``: "ref" where it would
+    be "compiled"."""
+    mode = kernel_mode()
+    return "ref" if mode == "compiled" else mode
+
+
 class _ShardCtx(NamedTuple):
     axis_name: str
     num_shards: int
@@ -128,8 +158,8 @@ def shard_context(axis_name: str, num_shards: int):
     operators and orthogonalization schemes read it back via
     ``shard_axis()`` / ``shard_size()`` to dispatch the per-shard kernels
     (halo-exchange SpMV, split-phase CGS2, CA matrix powers).  The
-    ``num_shards`` is needed wherever a static ``ppermute`` permutation is
-    built — jax < 0.5 has no ``lax.axis_size``.
+    ``num_shards`` travels with the context because the halo exchange
+    builds its ``ppermute`` permutation as a Python list at trace time.
     """
     _SHARD_CTX.append(_ShardCtx(str(axis_name), int(num_shards)))
     try:
@@ -369,6 +399,17 @@ def choose_matvec_blocks(m: int, n: int, dtype_name: str = "float32",
     return bm, bn
 
 
+def _ell_vmem(n: int, width: int, dtype, k: int, halo: int,
+              bm: int) -> int:
+    """VMEM of the ELL gather kernel at ``bm`` rows per grid step: the
+    (bm, width) values and int32 cols tiles and the (bm, k) output tile,
+    double-buffered, plus the whole (n + 2*halo, k) f32 operand."""
+    return (2 * vmem_bytes(bm, width, dtype)
+            + 2 * vmem_bytes(bm, width, jnp.int32)
+            + 2 * vmem_bytes(n + 2 * halo, k, jnp.float32)
+            + 2 * vmem_bytes(bm, k, jnp.float32))
+
+
 @persistent_choice
 def choose_spmv_block(n: int, width: int, dtype_name: str = "float32",
                       k: int = 1, halo: int = 0,
@@ -377,21 +418,18 @@ def choose_spmv_block(n: int, width: int, dtype_name: str = "float32",
 
     The gather kernel keeps the WHOLE operand x (n, k) resident in VMEM
     (sparse column patterns touch arbitrary rows of x, so tiling x would
-    re-stream it once per row block); per grid step it adds a
-    double-buffered (bm, width) values tile + int32 cols tile and the
-    (bm, k) f32 output tile.  We maximize the row block under the budget —
-    bigger blocks amortize the gather setup and the grid overhead.
+    re-stream it once per row block); per grid step it adds the values,
+    cols and output tiles (``_ell_vmem``).  We maximize the row block
+    under the budget — bigger blocks amortize the gather setup and the
+    grid overhead.
 
     ``halo``: extra resident operand rows on EACH side — the row-sharded
     halo variant gathers from a (n + 2*halo, k) exchanged operand.
     """
-    s = itemsize(dtype_name)
     sub = sublane(dtype_name)
-    resident = _round_up(n + 2 * halo, LANE) * k * 4   # x, promoted to f32
     best = sub
     for bm in (128, 256, 512, 1024, 2048):
-        need = 2 * bm * width * (s + 4) + resident + bm * k * 4
-        if need <= budget:
+        if _ell_vmem(n, width, dtype_name, k, halo, bm) <= budget:
             best = bm
     return min(best, _round_up(n, sub))
 
@@ -401,19 +439,14 @@ def spmv_fits(n: int, width: int, dtype, k: int = 1, halo: int = 0,
     """Can the gather SpMV kernel keep the full operand x in VMEM?
 
     This is the kernel's hard requirement (see ``choose_spmv_block``); when
-    it fails — n in the several-millions for f32 — the operator degrades to
-    the jnp gather reference, which XLA streams from HBM.  ``halo`` prices
-    the row-sharded variant's exchanged (n + 2*halo, k) operand; note the
+    it fails the operator degrades to the jnp gather reference, which XLA
+    streams from HBM.  The resident (n, k) operand pads its lane dim to
+    128, so n in the tens of thousands already overflows.  ``halo`` prices
+    the row-sharded variant's exchanged (n + 2*halo, k) operand; the
     sharded check runs on the LOCAL n, so sharding P-fold also divides the
-    residency requirement P-fold — the halo path FITS systems the
-    single-device kernel cannot hold.
+    residency requirement P-fold.
     """
-    s = itemsize(dtype)
-    sub = sublane(dtype)
-    need = (2 * sub * width * (s + 4)                  # min values+cols tiles
-            + _round_up(n + 2 * halo, LANE) * k * 4    # resident x (+ halo)
-            + sub * k * 4)                             # output tile
-    return need <= budget
+    return _ell_vmem(n, width, dtype, k, halo, sublane(dtype)) <= budget
 
 
 @persistent_choice
@@ -432,15 +465,12 @@ def choose_sell_block(n: int, rows: int, width: int,
     so a grid step covers whole slices (a block boundary inside a slice
     would split the one rectangle the format guarantees is dense).
     """
-    s = itemsize(dtype_name)
     sub = sublane(dtype_name)
-    resident = _round_up(n, LANE) * k * 4   # x, promoted to f32
     c = max(int(slice_height), sub)
     best = c
     bm = c
     while bm <= 4096:
-        need = 2 * bm * width * (s + 4) + resident + bm * k * 4
-        if need <= budget:
+        if _ell_vmem(n, width, dtype_name, k, 0, bm) <= budget:
             best = bm
         bm *= 2
     return min(best, _round_up(rows, sub))
@@ -459,36 +489,44 @@ def sell_fits(n: int, width: int, dtype, k: int = 1,
     return spmv_fits(n, width, dtype, k=k, halo=0, budget=budget)
 
 
+def _banded_vmem(n: int, nbands: int, dtype, halo: int, k: int,
+                 br: int) -> int:
+    """VMEM the lane-dense banded kernel allocates at ``br`` rows of 128
+    per grid step: the whole (k, rows, 128) f32 operand and the
+    (nbands, br, 128) band / (k, br, 128) output tiles, each
+    double-buffered by the pipeline, plus the window/accumulator values."""
+    f32 = jnp.float32
+    rows = _round_up(-(-(_round_up(halo, LANE) + _round_up(n, br * LANE)
+                         + halo) // LANE) + 1, 8)
+    return (2 * k * vmem_bytes(rows, LANE, f32)
+            + 2 * nbands * vmem_bytes(br, LANE, dtype)
+            + 2 * k * vmem_bytes(br, LANE, f32)
+            + 3 * vmem_bytes(br, LANE, f32))
+
+
 @persistent_choice
 def choose_banded_block(n: int, nbands: int, dtype_name: str = "float32",
                         halo: int = 0, k: int = 1,
                         budget: int = VMEM_BUDGET) -> int:
-    """Pick ``block_m`` for the banded/stencil SpMV kernel.
+    """Pick ``block_m`` (rows per grid step) for the banded/stencil kernel.
 
-    The kernel holds the halo-padded operand (n + 2*halo, k) resident in
-    VMEM (each band reads a shifted window of it) plus a double-buffered
-    (bm, nbands) bands tile and the (bm, k) output tile.
+    The kernel tiles rows as (br, 128) lane-dense blocks; the biggest
+    ``br`` whose working set (``_banded_vmem``) fits the budget wins.
     """
-    s = itemsize(dtype_name)
     sub = sublane(dtype_name)
-    resident = _round_up(n + 2 * halo, LANE) * k * 4
     best = sub
-    for bm in (128, 256, 512, 1024, 2048, 4096):
-        need = 2 * bm * nbands * s + resident + bm * k * 4
-        if need <= budget:
-            best = bm
-    return min(best, _round_up(n, sub))
+    for br in (8, 16, 32, 64, 128, 256, 512):
+        if br % sub == 0 and _banded_vmem(n, nbands, dtype_name, halo, k,
+                                          br) <= budget:
+            best = br
+    return min(best, _round_up(-(-n // LANE), sub)) * LANE
 
 
 def banded_fits(n: int, nbands: int, dtype, halo: int = 0, k: int = 1,
                 budget: int = VMEM_BUDGET) -> bool:
     """Can the banded kernel keep the halo-padded operand in VMEM?"""
-    s = itemsize(dtype)
-    sub = sublane(dtype)
-    need = (2 * sub * nbands * s
-            + _round_up(n + 2 * halo, LANE) * k * 4
-            + sub * k * 4)
-    return need <= budget
+    return _banded_vmem(n, nbands, dtype, halo, k,
+                        sublane(dtype)) <= budget
 
 
 @persistent_choice
@@ -516,20 +554,23 @@ def powers_fits(n: int, dtype, s: int, *, nbands: int | None = None,
                 halo: int = 0, budget: int = VMEM_BUDGET) -> bool:
     """Can the matrix-powers kernel keep its working set in VMEM?
 
-    Both variants carry the (s, n) power block, the current operand and the
-    w/halo scratch in f32; the banded variant (``nbands`` set) additionally
+    Both variants carry the (s, n) power block and the (1, n) operand,
+    double-buffered, and (1, n) f32 scratch rows — each row padded to the
+    8-row sublane tile; the banded variant (``nbands`` set) additionally
     holds the whole band stack resident (the point of the kernel: ONE HBM
     pass over A for all s powers), the dense variant one double-buffered
     A tile.  Failing the check sends the block step to the jnp reference.
     """
-    s_mat = itemsize(dtype)
+    f32 = jnp.float32
     np_ = _round_up(n, LANE)
-    vecs = np_ * 4 * (_round_up(s, sublane("float32")) + 2)
+    vecs = 2 * vmem_bytes(s, np_, f32) + 2 * vmem_bytes(1, np_, f32)
     if nbands is None:
         b = choose_powers_block(n, jnp.dtype(dtype).name, s=s, budget=budget)
-        need = vecs + 2 * b * b * s_mat
+        need = vecs + 2 * vmem_bytes(1, np_, f32) + 2 * vmem_bytes(b, b,
+                                                                   dtype)
     else:
-        need = vecs + nbands * np_ * s_mat + (np_ + 2 * halo) * 4
+        need = (vecs + 2 * vmem_bytes(nbands, np_, dtype)
+                + 4 * vmem_bytes(1, np_ + 2 * halo, f32))
     return need <= budget
 
 
@@ -627,22 +668,22 @@ def fused_step_fits(m1: int, n: int, dtype, budget: int = VMEM_BUDGET,
     """Can the fused Arnoldi-step kernel keep the whole basis V in VMEM?
 
     The fused kernel's peak working set is the Gram-Schmidt grid step: the
-    full (m1, n) basis in storage ``dtype`` PLUS its accumulator-dtype
-    upcast, the w accumulator, and one double-buffered A tile — priced in
+    full (m1, n) basis in storage ``dtype`` (double-buffered) PLUS its
+    accumulator-dtype upcast and one basis-sized product, the (1, n) w
+    row, and the double-buffered A tile and v_j row — priced in
     ``a_dtype`` (the matrix may be stored wider than the basis, e.g. f32 A
     with a bf16 ``compute_dtype`` basis).
     """
     if a_dtype is None:
         a_dtype = dtype
-    s = itemsize(dtype)
-    sa = itemsize(a_dtype)
-    acc = max(4, sa)                 # f32 accumulation; f64 under x64
+    acc = jnp.promote_types(a_dtype, jnp.float32)  # f64 under x64
     b = choose_fused_block(n, a_dtype, budget)
-    m1p = _round_up(m1, sublane(dtype))
     np_ = _round_up(n, b)
-    need = (m1p * np_ * (s + acc)    # resident V + in-kernel upcast
-            + np_ * acc * 2          # w accumulator + orthogonalized copy
-            + 2 * b * b * sa)        # double-buffered A tile
+    need = (2 * vmem_bytes(m1, np_, dtype)       # resident V
+            + 2 * vmem_bytes(m1, np_, acc)       # upcast + one product
+            + 2 * vmem_bytes(1, np_, acc)        # w row, in and out
+            + 2 * vmem_bytes(b, b, a_dtype)      # A tile
+            + 2 * vmem_bytes(1, b, a_dtype))     # v_j row
     return need <= budget
 
 
@@ -651,36 +692,45 @@ def cheb_fits(n: int, nbands: int, dtype, *, halo: int = 0,
     """Can the fused Chebyshev-apply kernel keep its working set in VMEM?
 
     The kernel is grid-free: the whole band stack, the input v and the
-    three recurrence vectors (z, z_old, the stencil accumulator) stay
-    resident for all ``order`` matvecs, plus the halo-padded z scratch.
-    This is the EXPLICIT dispatch gate for ``banded_cheb_apply`` — on
-    overflow (and in tests forcing it) the preconditioner degrades to the
-    psum-safe per-matvec recurrence through the operator.
+    (1, n) recurrence rows (z, z_old, z_new, the stencil accumulator) stay
+    resident for all ``order`` matvecs, plus the halo-padded z scratch —
+    each (1, n) row padded to the 8-row sublane tile.  This is the
+    EXPLICIT dispatch gate for ``banded_cheb_apply`` — on overflow (and in
+    tests forcing it) the preconditioner degrades to the psum-safe
+    per-matvec recurrence through the operator.
     """
-    s = itemsize(dtype)
+    f32 = jnp.float32
     np_ = _round_up(n, LANE)
-    need = (nbands * np_ * s            # resident band stack
-            + np_ * 4 * 4               # v, z, z_old, w (f32)
-            + (np_ + 2 * halo) * 4)     # halo-padded z scratch
+    need = (2 * vmem_bytes(nbands, np_, dtype)     # resident band stack
+            + 8 * vmem_bytes(1, np_, f32)          # v, out, recurrence rows
+            + vmem_bytes(1, np_ + 2 * halo, f32))  # halo-padded z scratch
     return need <= budget
 
 
+def _trisweep_vmem(k: int, bm: int) -> int:
+    """VMEM the triangular-sweep kernel allocates at ``bm`` rows per grid
+    step.  One matrix row per sublane, so every tile pads its lane dim to
+    128: the (bm, nbands) f32 bands tile and the (bm, 1) v / z tiles, each
+    double-buffered, plus the (k + bm, 1) f32 carry ring."""
+    return 6 * vmem_bytes(bm, 1, jnp.float32) + vmem_bytes(k + bm, 1,
+                                                          jnp.float32)
+
+
 @persistent_choice
-def choose_trisweep_block(n: int, nbands: int, k: int = 1,
+def choose_trisweep_block(n: int, k: int = 1,
                           budget: int = VMEM_BUDGET) -> int:
     """Row-block size for the banded triangular-sweep kernel.
 
     The sweep is sequential in rows, so the block only sizes the VMEM
-    tiles (bands, v, z, and the (1, k + bm) carry ring) — bigger blocks
-    amortize grid overhead; the floor is the carry depth k (the shift
-    ``zp[:k] = zp[bm:bm+k]`` needs bm >= k).
+    tiles — bigger blocks amortize grid overhead; the floor is the carry
+    depth k (the shift ``zp[:k] = zp[bm:bm+k]`` needs bm >= k).
     """
-    best = LANE
+    floor = _round_up(max(k, 8), 8)
+    best = floor
     for bm in (128, 256, 512, 1024, 2048, 4096):
-        need = (2 * bm * nbands * 4 + 3 * bm * 4 + (k + bm) * 4)
-        if need <= budget:
+        if bm >= floor and _trisweep_vmem(k, bm) <= budget:
             best = bm
-    return max(best, _round_up(k, LANE))
+    return min(best, max(_round_up(n, 8), floor))
 
 
 def trisweep_fits(n: int, nbands: int, dtype, *, k: int = 1,
@@ -688,25 +738,26 @@ def trisweep_fits(n: int, nbands: int, dtype, *, k: int = 1,
     """Can the triangular-sweep kernel hold a row block + carry ring?
 
     The EXPLICIT dispatch gate for ``kernels/trisolve.banded_trisweep`` —
-    overflow (and tests forcing it) degrades to the lax.scan reference,
+    overflow (and tests forcing it) degrades to the blocked jnp reference,
     which computes the identical recurrence.
     """
-    bm = _round_up(max(k, LANE), LANE)
-    need = 2 * bm * nbands * itemsize(dtype) + 3 * bm * 4 + (k + bm) * 4
-    return need <= budget
+    del n, nbands, dtype  # the kernel tiles rows in f32 whatever the size
+    return _trisweep_vmem(k, _round_up(max(k, 8), 8)) <= budget
 
 
 def ell_powers_fits(n: int, width: int, dtype, s: int,
                     budget: int = VMEM_BUDGET) -> bool:
     """Can the ELL matrix-powers kernel keep values+cols+powers in VMEM?
 
-    Mirrors ``powers_fits``: the (s, n) normalized power block, current
-    operand and w accumulator in f32, plus the WHOLE (n, width)
+    Mirrors ``powers_fits``: the (s, n) normalized power block and the
+    (1, n) operand and current rows in f32, plus the WHOLE (n, width)
     values/cols pair resident (the sparse gather may touch any row, and
-    one residency pays for all s powers).  Failing the check sends the
-    s-step block to the jnp reference powers.
+    one residency pays for all s powers) — its lane dim padded to 128.
+    Failing the check sends the s-step block to the jnp reference.
     """
+    f32 = jnp.float32
     np_ = _round_up(n, LANE)
-    vecs = np_ * 4 * (_round_up(s, sublane("float32")) + 2)
-    need = vecs + np_ * width * (itemsize(dtype) + 4)
+    need = (2 * vmem_bytes(s, np_, f32) + 3 * vmem_bytes(1, np_, f32)
+            + 2 * vmem_bytes(np_, width, dtype)
+            + 2 * vmem_bytes(np_, width, jnp.int32))
     return need <= budget

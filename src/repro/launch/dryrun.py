@@ -33,7 +33,6 @@ import traceback
 
 import jax
 
-from repro import compat
 
 from repro import configs
 from repro.launch.mesh import make_production_mesh
@@ -42,7 +41,10 @@ from repro.launch.steps import (abstract_state, make_optimizer,
                                 make_train_step, state_shardings)
 from repro.models import (SHAPES, active_param_count, build, cache_specs,
                           input_specs, shape_applicable)
-from repro.roofline import analyze, model_flops_for
+from repro.roofline import analyze, chip_peaks, model_flops_for
+
+# The chip the production meshes model (their devices here are CPU stand-ins).
+TARGET_KIND = "TPU v5 lite"
 
 
 def _depth_variant(cfg, k: int):
@@ -95,14 +97,15 @@ def _lower_one(cfg, shape, mesh):
 
 def _stats_of(compiled):
     from repro.roofline import parse_collectives
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     colls = parse_collectives(hlo)
     return {
         "flops": float(cost.get("flops", 0.0)),
         "bytes": float(cost.get("bytes accessed", 0.0)),
         "coll_bytes": float(sum(c.result_bytes * c.count for c in colls)),
-        "coll_s": float(sum(c.ring_seconds() * c.count for c in colls)),
+        "coll_s": float(sum(c.ring_seconds(chip_peaks(TARGET_KIND).link_bw)
+                            * c.count for c in colls)),
     }
 
 
@@ -146,7 +149,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         else:
             corrected, depth_info = raw, {}
 
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     try:
         mem = compiled.memory_analysis()
         bytes_per_device = getattr(mem, "output_size_in_bytes", None)
@@ -167,7 +170,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
               "bytes accessed": corrected["bytes"]},
         hlo_text=hlo,
         model_flops=model_flops_for(cfg, shape, active_param_count(cfg)),
-        bytes_per_device=bytes_per_device)
+        device_kind=TARGET_KIND, bytes_per_device=bytes_per_device)
     # override HLO-text collective stats with the depth-corrected ones
     roof = dataclasses.replace(roof,
                                collective_bytes=corrected["coll_bytes"],

@@ -14,21 +14,39 @@ cost_analysis — we parse the optimized HLO text and sum operand sizes of
 all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute,
 with replica_groups giving each op's group size.
 
-Hardware constants: TPU v5e — 197 bf16 TFLOP/s, 819 GB/s HBM, ~50 GB/s/link
-ICI (we model ring collectives at 2 simultaneous link directions per chip:
-eff_bw = 2 x 45 GB/s usable).
+Hardware constants come from ``PEAKS``, keyed by the ``device_kind`` JAX
+reports; a kind missing from the table is an error, never a default.
 """
 from __future__ import annotations
 
 import dataclasses
 import json
 import re
-from typing import Optional
+from typing import NamedTuple, Optional
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-LINK_BW = 45e9               # usable bytes/s per ICI link direction
+
+class ChipPeaks(NamedTuple):
+    flops: float        # dense bf16 FLOP/s per chip
+    hbm_bw: float       # HBM bytes/s per chip
+    link_bw: float      # usable bytes/s per ICI link direction
+
+
+# Per-chip peaks by ``jax.Device.device_kind``.  TPU v5e ("TPU v5 lite"):
+# 197 TFLOP/s bf16, 819 GB/s HBM and 1,600 Gbit/s of interconnect per chip
+# (Google Cloud documentation, "TPU v5e"); the ring model below takes
+# 45 GB/s usable per link direction.
+PEAKS = {"TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, link_bw=45e9)}
 RING_LINKS = 2               # ring uses both directions of one axis
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Published peaks of ``device_kind``; ValueError when not in PEAKS."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -65,10 +83,10 @@ class CollectiveOp:
     group_size: int
     count: int = 1
 
-    def ring_seconds(self) -> float:
+    def ring_seconds(self, link_bw: float) -> float:
         g = max(self.group_size, 2)
         eff = (g - 1) / g
-        bw = LINK_BW * RING_LINKS
+        bw = link_bw * RING_LINKS
         if self.kind == "all-reduce":
             return 2 * self.result_bytes * eff / bw
         if self.kind == "collective-permute":
@@ -210,16 +228,17 @@ class Roofline:
 
 
 def analyze(*, arch: str, shape: str, mesh_name: str, chips: int,
-            cost: dict, hlo_text: str, model_flops: float,
+            cost: dict, hlo_text: str, model_flops: float, device_kind: str,
             bytes_per_device: Optional[float] = None,
             notes: str = "") -> Roofline:
+    peaks = chip_peaks(device_kind)
     flops = float(cost.get("flops", 0.0))
     hbm = float(cost.get("bytes accessed", 0.0))
     colls = parse_collectives(hlo_text)
     coll_bytes = sum(c.result_bytes * c.count for c in colls)
-    coll_s = sum(c.ring_seconds() * c.count for c in colls)
-    compute_s = flops / PEAK_FLOPS
-    memory_s = hbm / HBM_BW
+    coll_s = sum(c.ring_seconds(peaks.link_bw) * c.count for c in colls)
+    compute_s = flops / peaks.flops
+    memory_s = hbm / peaks.hbm_bw
     terms = {"compute": compute_s, "memory": memory_s, "collective": coll_s}
     bottleneck = max(terms, key=terms.get)
     useful = model_flops / max(flops * chips, 1.0)

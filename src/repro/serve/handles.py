@@ -9,8 +9,8 @@ those buckets — the compiled-executable complement of the on-disk
 ``persistent_choice`` cache, which already makes the tile choices INSIDE
 the lowering restart-stable.
 
-The handle jit-closes over the CONCRETE operator (and gs scheme and
-preconditioner), not just its shape — so the cache key must too.
+A handle serves ONE concrete operator (and gs scheme and
+preconditioner), not just a shape — so the cache key must name it too.
 :class:`HandleKey` therefore carries identity tokens for the operator
 and preconditioner alongside the shape bucket: two servers sharing one
 cache over same-shaped but different operators get two handles, never
@@ -60,9 +60,9 @@ class HandleKey(NamedTuple):
     """LRU key: everything that changes the lowered cycle.
 
     The shape bucket ``(n, fmt, m, k, dtype)`` sizes the executable; the
-    identity fields pin WHICH system it solves — the handle closes over
-    the operator, gs scheme, and preconditioner, so a key that ignored
-    them would hand a same-shaped server the wrong compiled solve.
+    identity fields pin WHICH system it solves — the handle holds the
+    operator, gs scheme, and preconditioner, so a key that ignored them
+    would hand a same-shaped server the wrong solve.
     """
 
     n: int
@@ -88,9 +88,9 @@ class SolverHandle:
 
     ``jax.jit`` is lazy, so constructing a handle is cheap; the compile
     lands on the first ``cycle`` call and is keyed by the (k, n) block
-    shapes, which the handle pins.  The operator itself is a static
-    closure — one handle per A, which is the batched engine's contract
-    (ONE A stream shared by all k lanes).
+    shapes, which the handle pins.  The handle holds its operator and
+    passes it to every cycle — one handle per A, which is the batched
+    engine's contract (ONE A stream shared by all k lanes).
     """
 
     def __init__(self, op, *, m: int = 30, k: int = 8,
@@ -101,8 +101,11 @@ class SolverHandle:
         self.key = _handle_key(op, m=m, k=k, dtype=dtype, gs=gs,
                                precond=precond)
         self.gs = gs
+        # The operator is a jit ARGUMENT, not a closure: a closed-over
+        # array would be baked into the program as a literal (268 MB for
+        # an 8192^2 f32 matrix), bloating compile time and memory.
         self._cycle = jax.jit(functools.partial(
-            gmres_batched_cycle, op, m=int(m), gs=gs, precond=precond,
+            gmres_batched_cycle, m=int(m), gs=gs, precond=precond,
             compute_dtype=dtype))
         self.cycles_run = 0
 
@@ -121,6 +124,15 @@ class SolverHandle:
     def block_shape(self) -> Tuple[int, int]:
         return (self.key.k, self.key.n)
 
+    def lower(self):
+        """The cycle lowered for this handle's block shapes (all lanes
+        active), e.g. to ``.compile()`` and read the program."""
+        dt = jnp.dtype(self.key.dtype)
+        blk = jnp.zeros(self.block_shape(), dt)
+        return self._cycle.lower(self.op, blk, blk,
+                                 tol_abs=jnp.zeros((self.k,), dt),
+                                 active=jnp.ones((self.k,), bool))
+
     def cycle(self, b, x, tol_abs, active):
         """One lockstep restart cycle; returns ``(x', beta', inner_steps)``.
 
@@ -135,7 +147,7 @@ class SolverHandle:
             raise ValueError(
                 f"handle {self.key} expects {self.block_shape()} blocks, "
                 f"got b{b.shape} x{x.shape}")
-        out = self._cycle(b, x, tol_abs=jnp.asarray(tol_abs, dt),
+        out = self._cycle(self.op, b, x, tol_abs=jnp.asarray(tol_abs, dt),
                           active=jnp.asarray(active, bool))
         self.cycles_run += 1
         return out

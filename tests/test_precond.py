@@ -114,12 +114,13 @@ def test_ilu0_pentadiagonal_residual_small():
     assert np.abs(resid).max() < 0.5
 
 
+@pytest.mark.parametrize("reach", [2, 16])   # 16: a 2-D stencil's -nx band
 @pytest.mark.parametrize("lower,unit", [(True, True), (True, False),
                                         (False, False)])
-def test_trisweep_kernel_matches_ref(lower, unit):
+def test_trisweep_kernel_matches_ref(lower, unit, reach):
     key = jax.random.PRNGKey(7)
     n = 200
-    offs = (-2, -1, 0) if lower else (0, 1, 2)
+    offs = (-reach, -1, 0) if lower else (0, 1, reach)
     bands = jax.random.uniform(key, (3, n), minval=0.2, maxval=1.0)
     bands = bands.at[offs.index(0)].add(2.0)
     bands = trisolve._mask_oob(bands, offs)

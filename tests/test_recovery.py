@@ -164,9 +164,11 @@ def test_ladder_full_from_top():
     rungs = build_ladder("cgs2_pipelined", mode="compiled")
     assert rungs[0] == ("cgs2_pipelined", "compiled")
     assert rungs[-1] == ("mgs", "ref")
-    # 4 schemes at each of 3 modes.
-    assert len(rungs) == 12
-    assert rungs[4] == ("cgs2_pipelined", "interpret")
+    # 4 schemes at each of 2 modes: a compiled solve steps straight to the
+    # reference, never into the Pallas interpreter.
+    assert len(rungs) == 8
+    assert rungs[4] == ("cgs2_pipelined", "ref")
+    assert all(md != "interpret" for _, md in rungs)
 
 
 def test_ladder_starts_at_callers_scheme():
@@ -379,6 +381,21 @@ def test_every_rung_converges():
     assert not rep.gave_up and bool(res.converged)
     assert float(res.residual) <= tol * float(jnp.linalg.norm(b))
     assert int(res.restarts) - int(ref.restarts) <= 1
+
+
+def test_kernel_compile_refusal_propagates(monkeypatch, tmp_path):
+    """A kernel the compiler refuses is a program error: it surfaces from
+    the stepped loop instead of being retried or stepped down past."""
+    from repro.kernels import cgs2 as cgs2_k
+
+    def refuse(*a, **k):
+        raise ValueError("kernel lowering refused")
+
+    monkeypatch.setattr(cgs2_k, "gs_update", refuse)
+    op, b = _op(), _rhs(48)
+    with pytest.raises(ValueError, match="kernel lowering refused"):
+        gmres_self_healing(op, b, m=3, tol=1e-6, max_restarts=5,
+                           gs="cgs2_pipelined", checkpoint_dir=tmp_path)
 
 
 def test_transient_exception_absorbed_by_retries():

@@ -303,7 +303,10 @@ def test_choose_spmv_block_respects_budget():
 
 
 def test_spmv_fits_rejects_vmem_overflow():
-    assert tuning.spmv_fits(65536, 5, jnp.float32)
+    # The resident (n, 1) operand pads its lane dim to 128 in VMEM (512
+    # bytes a row): 8k rows fit the budget, 64k rows do not.
+    assert tuning.spmv_fits(8192, 5, jnp.float32)
+    assert not tuning.spmv_fits(65536, 5, jnp.float32)
     # an operand too large to sit in VMEM must push the op to the jnp path
     assert not tuning.spmv_fits(8_000_000, 5, jnp.float32)
     assert tuning.banded_fits(65536, 5, jnp.float32, halo=256)

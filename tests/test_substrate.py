@@ -242,3 +242,13 @@ def test_roofline_collective_parser():
     assert ag.group_size == 4
     ar = next(o for o in ops if o.kind == "all-reduce")
     assert ar.group_size == 16
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """Peaks come from the device kind JAX reports; an unknown kind is an
+    error, never v5e's numbers."""
+    from repro.roofline import chip_peaks
+    v5e = chip_peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(ValueError, match="no published peaks"):
+        chip_peaks("TPU v4")

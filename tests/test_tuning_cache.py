@@ -139,3 +139,28 @@ def test_gs_payload_fits_gate():
     assert tuning.gs_payload_fits(33, 8192, "float32")
     assert not tuning.gs_payload_fits(33, 8192, "float32", budget=16)
     assert not tuning.gs_payload_fits(33, 0, "float32")
+
+
+def test_compile_cache_dir_is_fixed(tmp_path, monkeypatch):
+    """JAX's persistent compile cache: the directory the environment names
+    is left to JAX itself; without it the cache goes to ``.jax_cache/`` at
+    the repository root — one fixed path, so a later run finds it."""
+    import jax
+
+    from repro.runtime import compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(compile_cache.ENV, str(tmp_path))
+    assert compile_cache.configure() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == prev
+    monkeypatch.delenv(compile_cache.ENV)
+    try:
+        assert compile_cache.configure() == str(compile_cache.REPO_CACHE)
+        assert jax.config.jax_compilation_cache_dir == str(
+            compile_cache.REPO_CACHE)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+    root = compile_cache.REPO_CACHE.parent
+    assert compile_cache.REPO_CACHE.name == ".jax_cache"
+    assert (root / "chip_smoke.py").is_file()
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
