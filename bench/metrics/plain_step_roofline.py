@@ -1,0 +1,13 @@
+"""plain_step_roofline: the whole time step's share of the HBM roofline
+(with no preconditioner): the least bytes of every traced step's solve at peak
+bandwidth, over the traced window."""
+
+from bench import roofline
+
+
+def read(run):
+    if run.trace is None or not run.solves:
+        return None
+    nbytes = sum(sum(roofline.solve_parts(run, s).values())
+                 for s in run.solves if s["traced"])
+    return roofline.share(run, nbytes, run.trace.window_s)
