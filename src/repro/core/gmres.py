@@ -30,6 +30,16 @@ kernel-backed there too: under the distributed wrapper's
 all-gather per-shard mat-vecs and ``gs="cgs2_fused"`` runs the
 split-phase CGS2 kernel pair with the h psum between the phases.  There
 is exactly ONE cycle implementation for local and distributed solves.
+
+Every cycle names its parts with ``jax.named_scope``, which lands in the
+compiled program's ``op_name`` metadata (no run-time cost): ``operator``
+(each application of A with the staging of its operand, the pads of A
+included), ``precond`` (each M^{-1} apply), ``orthogonalize`` (the
+Gram-Schmidt scheme and the basis reads and writes), ``arnoldi_step``
+(the fused single-kernel step, operator and orthogonalization together),
+``lsq`` (the Givens updates, the triangular solve and the solution
+update) and ``residual`` (the true-residual recompute; its mat-vec is
+``residual/operator``).  A profiler trace's device ops map to them.
 """
 from __future__ import annotations
 
@@ -200,14 +210,16 @@ def _make_step_fn(matvec, precond, gs: str, axis_name, *, identity_precond,
             block = tuning.choose_fused_block(n, a_dtype)
             n_pad = tuning._round_up(n, block)
             m1_pad = tuning._round_up(m + 1, tuning.sublane(basis_dtype))
-            a_pad = jnp.pad(matvec.a.astype(a_dtype),
-                            ((0, n_pad - n), (0, n_pad - n)))
+            with jax.named_scope("operator"):
+                a_pad = jnp.pad(matvec.a.astype(a_dtype),
+                                ((0, n_pad - n), (0, n_pad - n)))
 
             def fused_step(v_basis, j):
-                h, w = arnoldi_fused.arnoldi_step(a_pad, v_basis, j,
-                                                  block=block,
-                                                  interpret=interp)
-                return arnoldi.finalize(w, h[:m + 1], j, None)
+                with jax.named_scope("arnoldi_step"):
+                    h, w = arnoldi_fused.arnoldi_step(a_pad, v_basis, j,
+                                                      block=block,
+                                                      interpret=interp)
+                    return arnoldi.finalize(w, h[:m + 1], j, None)
 
             fused_step.basis_shape = (m1_pad, n_pad)
             return fused_step
@@ -216,8 +228,14 @@ def _make_step_fn(matvec, precond, gs: str, axis_name, *, identity_precond,
     gs_step = arnoldi.step(gs)
 
     def step(v_basis, j):
-        w = matvec(precond(v_basis[j]))
-        return gs_step(v_basis, w, j, axis_name)
+        with jax.named_scope("orthogonalize"):
+            vj = v_basis[j]
+        with jax.named_scope("precond"):
+            z = precond(vj)
+        with jax.named_scope("operator"):
+            w = matvec(z)
+        with jax.named_scope("orthogonalize"):
+            return gs_step(v_basis, w, j, axis_name)
 
     return step
 
@@ -244,11 +262,15 @@ def _gmres_cycle(step_fn, x0, r0, beta, m, tol_abs, precond, basis_dtype):
     # Kernel-backed steps may ask for a tile-aligned carry (see
     # _make_step_fn); padded rows/columns are zero and never touched.
     basis_rows, basis_cols = getattr(step_fn, "basis_shape", (m + 1, n))
-    v0 = (r0 / jnp.maximum(beta, eps)).astype(basis_dtype)
-    v = jnp.zeros((basis_rows, basis_cols), basis_dtype).at[0, :n].set(v0)
+    with jax.named_scope("orthogonalize"):
+        v0 = (r0 / jnp.maximum(beta, eps)).astype(basis_dtype)
+        v = jnp.zeros((basis_rows, basis_cols), basis_dtype).at[0, :n].set(
+            v0)
+    with jax.named_scope("lsq"):
+        giv = givens.init(m, beta, dtype)
     state = _CycleState(
         v=v,
-        giv=givens.init(m, beta, dtype),
+        giv=giv,
         done=beta <= tol_abs,
         steps=jnp.zeros((), jnp.int32),
     )
@@ -260,19 +282,26 @@ def _gmres_cycle(step_fn, x0, r0, beta, m, tol_abs, precond, basis_dtype):
         j = s.steps
         # --- Arnoldi: w = A M^{-1} v_j, orthogonalize against V[:j+1] ---
         st = step_fn(s.v, j)
-        v = s.v.at[j + 1, :st.v_next.shape[0]].set(st.v_next.astype(basis_dtype))
+        with jax.named_scope("orthogonalize"):
+            v = s.v.at[j + 1, :st.v_next.shape[0]].set(
+                st.v_next.astype(basis_dtype))
         # --- Givens: fold column j, track LS residual ---
-        giv = givens.update(s.giv, st.h.astype(dtype), j,
-                            active=jnp.asarray(True))
-        resid = givens.residual_norm(giv, j)
-        happy = st.h_last <= eps * 100.0
-        done = (resid <= tol_abs) | happy
+        with jax.named_scope("lsq"):
+            giv = givens.update(s.giv, st.h.astype(dtype), j,
+                                active=jnp.asarray(True))
+            resid = givens.residual_norm(giv, j)
+            happy = st.h_last <= eps * 100.0
+            done = (resid <= tol_abs) | happy
         return _CycleState(v=v, giv=giv, done=done, steps=j + 1)
 
     state = lax.while_loop(cond, body, state)
-    y = givens.solve(state.giv, state.steps)          # zeros past early stop
-    dx = y @ state.v[:m, :n].astype(dtype)            # V^T y with row basis
-    x = x0 + precond(dx)
+    with jax.named_scope("lsq"):
+        y = givens.solve(state.giv, state.steps)      # zeros past early stop
+        dx = y @ state.v[:m, :n].astype(dtype)        # V^T y with row basis
+    with jax.named_scope("precond"):
+        dx = precond(dx)
+    with jax.named_scope("lsq"):
+        x = x0 + dx
     return x, state.steps
 
 
@@ -319,7 +348,10 @@ def _make_pipelined_fns(matvec, precond, axis_name, *, m: int, n: int,
             return out.astype(w.dtype)
 
     def op(zv):
-        return matvec(precond(zv))
+        with jax.named_scope("precond"):
+            z = precond(zv)
+        with jax.named_scope("operator"):
+            return matvec(z)
 
     return op, update
 
@@ -358,14 +390,20 @@ def _gmres_cycle_pipelined(op, update, x0, r0, beta, m, tol_abs, precond,
     eps_rel = jnp.asarray(jnp.finfo(dtype).eps * 100.0, dtype)
     acc = jnp.promote_types(dtype, jnp.float32)
 
-    v0 = (r0 / jnp.maximum(beta, tiny)).astype(dtype)
-    v = jnp.zeros((m + 1, n), basis_dtype).at[0].set(v0.astype(basis_dtype))
+    with jax.named_scope("orthogonalize"):
+        v0 = (r0 / jnp.maximum(beta, tiny)).astype(dtype)
+        v = jnp.zeros((m + 1, n), basis_dtype).at[0].set(
+            v0.astype(basis_dtype))
+        hraw = jnp.zeros((m + 1, m), dtype)
+        gram = jnp.eye(m + 1, dtype=acc)
+    with jax.named_scope("lsq"):
+        giv = givens.init(m, beta, dtype)
     state = _PipelinedState(
         v=v,
         z=op(v0),                               # pipeline prologue mat-vec
-        hraw=jnp.zeros((m + 1, m), dtype),
-        gram=jnp.eye(m + 1, dtype=acc),
-        giv=givens.init(m, beta, dtype),
+        hraw=hraw,
+        gram=gram,
+        giv=giv,
         done=beta <= tol_abs,
         steps=jnp.zeros((), jnp.int32),
     )
@@ -376,35 +414,44 @@ def _gmres_cycle_pipelined(op, update, x0, r0, beta, m, tol_abs, precond,
     def body(s: _PipelinedState):
         j = s.steps
         # --- issue the ONE collective of this step ---
-        payload = arnoldi.sr_payload(s.v, s.z, j, axis_name)
+        with jax.named_scope("orthogonalize"):
+            payload = arnoldi.sr_payload(s.v, s.z, j, axis_name)
         # --- the next mat-vec, independent of the psum result ---
         u = op(s.z)
         # --- consume: replicated recovery of both passes + norm ---
-        h_tot, s_norm, zeta, gram = arnoldi.sr_recover(payload, s.gram, j)
-        h_tot = h_tot.astype(dtype)
-        s_d = s_norm.astype(dtype)
-        sg = jnp.maximum(s_d, tiny)
-        w2 = update(s.v, s.z, h_tot)            # w'' = z - h_tot @ V
-        v_next = w2 / sg
-        # correct the speculative mat-vec onto v_{j+1} via the recurrence
-        lt = (jnp.arange(m) < j).astype(dtype)
-        c_vec = s.hraw @ (h_tot[:m] * lt)       # (m+1,) basis coefficients
-        z_next = (update(s.v, u, c_vec) - h_tot[j] * s.z) / sg
+        with jax.named_scope("orthogonalize"):
+            h_tot, s_norm, zeta, gram = arnoldi.sr_recover(payload, s.gram,
+                                                           j)
+            h_tot = h_tot.astype(dtype)
+            s_d = s_norm.astype(dtype)
+            sg = jnp.maximum(s_d, tiny)
+            w2 = update(s.v, s.z, h_tot)        # w'' = z - h_tot @ V
+            v_next = w2 / sg
+            # correct the speculative mat-vec onto v_{j+1} via the
+            # recurrence
+            lt = (jnp.arange(m) < j).astype(dtype)
+            c_vec = s.hraw @ (h_tot[:m] * lt)   # (m+1,) basis coefficients
+            z_next = (update(s.v, u, c_vec) - h_tot[j] * s.z) / sg
 
-        v = s.v.at[j + 1].set(v_next.astype(basis_dtype))
-        hcol = h_tot.at[j + 1].set(s_d)
-        hraw = s.hraw.at[:, j].set(hcol)
-        giv = givens.update(s.giv, hcol, j, active=jnp.asarray(True))
-        resid = givens.residual_norm(giv, j)
-        happy = s_d <= eps_rel * jnp.sqrt(zeta).astype(dtype)
-        done = (resid <= tol_abs) | happy
+            v = s.v.at[j + 1].set(v_next.astype(basis_dtype))
+            hcol = h_tot.at[j + 1].set(s_d)
+            hraw = s.hraw.at[:, j].set(hcol)
+        with jax.named_scope("lsq"):
+            giv = givens.update(s.giv, hcol, j, active=jnp.asarray(True))
+            resid = givens.residual_norm(giv, j)
+            happy = s_d <= eps_rel * jnp.sqrt(zeta).astype(dtype)
+            done = (resid <= tol_abs) | happy
         return _PipelinedState(v=v, z=z_next, hraw=hraw, gram=gram, giv=giv,
                                done=done, steps=j + 1)
 
     state = lax.while_loop(cond, body, state)
-    y = givens.solve(state.giv, state.steps)
-    dx = y @ state.v[:m].astype(dtype)
-    x = x0 + precond(dx)
+    with jax.named_scope("lsq"):
+        y = givens.solve(state.giv, state.steps)
+        dx = y @ state.v[:m].astype(dtype)
+    with jax.named_scope("precond"):
+        dx = precond(dx)
+    with jax.named_scope("lsq"):
+        x = x0 + dx
     return x, state.steps
 
 
@@ -489,12 +536,16 @@ def gmres(
                                 identity_precond=identity_precond, m=m,
                                 n=b.shape[0], basis_dtype=basis_dtype)
 
-    bnorm = arnoldi.norm(b, axis_name)
-    tol_abs = jnp.maximum(tol * bnorm, jnp.asarray(0.0, b.dtype))
+    with jax.named_scope("residual"):
+        bnorm = arnoldi.norm(b, axis_name)
+        tol_abs = jnp.maximum(tol * bnorm, jnp.asarray(0.0, b.dtype))
 
     def resid_of(x):
-        r = b - matvec(x)
-        return r, arnoldi.norm(r, axis_name)
+        with jax.named_scope("residual"):
+            with jax.named_scope("operator"):
+                ax = matvec(x)
+            r = b - ax
+            return r, arnoldi.norm(r, axis_name)
 
     r0, beta0 = resid_of(x0)
     # Bounded residual-history ring, chronological with inf padding on the
@@ -516,7 +567,8 @@ def gmres(
                 step_fn, x, r, beta, m, tol_abs, precond, basis_dtype
             )
         r, beta = resid_of(x)
-        hist = jnp.roll(hist, -1).at[-1].set(beta)
+        with jax.named_scope("residual"):
+            hist = jnp.roll(hist, -1).at[-1].set(beta)
         return x, r, beta, k + 1, steps + inner, hist
 
     x, r, beta, k, steps, hist = lax.while_loop(
@@ -605,10 +657,12 @@ def _block_cycle(blockmv, vprecond, batched_gs, x0, r0, beta, m, tol_abs,
     # Kernel-backed GS may ask for tile-aligned lane bases (see
     # _make_batched_gs); padded rows/columns are zero and never touched.
     basis_rows, basis_cols = getattr(batched_gs, "basis_shape", (m + 1, n))
-    v0 = (r0 / jnp.maximum(beta, eps)[:, None]).astype(basis_dtype)
-    v = jnp.zeros((k, basis_rows, basis_cols), basis_dtype).at[
-        :, 0, :n].set(v0)
-    giv = jax.vmap(lambda be: givens.init(m, be, dtype))(beta)
+    with jax.named_scope("orthogonalize"):
+        v0 = (r0 / jnp.maximum(beta, eps)[:, None]).astype(basis_dtype)
+        v = jnp.zeros((k, basis_rows, basis_cols), basis_dtype).at[
+            :, 0, :n].set(v0)
+    with jax.named_scope("lsq"):
+        giv = jax.vmap(lambda be: givens.init(m, be, dtype))(beta)
     done = jnp.logical_not(active0) | (beta <= tol_abs)
     steps = jnp.zeros((k,), jnp.int32)
 
@@ -621,25 +675,35 @@ def _block_cycle(blockmv, vprecond, batched_gs, x0, r0, beta, m, tol_abs,
         j = steps                                     # per-lane step index
         active = jnp.logical_not(done) & (steps < m)
         # --- the k current Krylov vectors hit A as ONE GEMM ---
-        vj = jax.vmap(lambda vb, jj: vb[jj, :n])(v, j).astype(dtype)
-        w = blockmv(vprecond(vj))                     # (k, n)
-        st = batched_gs(v, w, j)
-        v_new = jax.vmap(lambda vb, vn, jj: vb.at[jj + 1, :n].set(vn))(
-            v, st.v_next.astype(basis_dtype), j)
-        v = jnp.where(active[:, None, None], v_new, v)
-        giv = jax.vmap(
-            lambda g, h, jj, act: givens.update(g, h, jj, active=act)
-        )(giv, st.h.astype(dtype), j, active)
-        resid = jax.vmap(givens.residual_norm)(giv, j)
-        happy = st.h_last <= eps * 100.0
-        done = done | (active & ((resid <= tol_abs) | happy))
-        steps = steps + active.astype(jnp.int32)
+        with jax.named_scope("orthogonalize"):
+            vj = jax.vmap(lambda vb, jj: vb[jj, :n])(v, j).astype(dtype)
+        with jax.named_scope("precond"):
+            z = vprecond(vj)
+        with jax.named_scope("operator"):
+            w = blockmv(z)                            # (k, n)
+        with jax.named_scope("orthogonalize"):
+            st = batched_gs(v, w, j)
+            v_new = jax.vmap(lambda vb, vn, jj: vb.at[jj + 1, :n].set(vn))(
+                v, st.v_next.astype(basis_dtype), j)
+            v = jnp.where(active[:, None, None], v_new, v)
+        with jax.named_scope("lsq"):
+            giv = jax.vmap(
+                lambda g, h, jj, act: givens.update(g, h, jj, active=act)
+            )(giv, st.h.astype(dtype), j, active)
+            resid = jax.vmap(givens.residual_norm)(giv, j)
+            happy = st.h_last <= eps * 100.0
+            done = done | (active & ((resid <= tol_abs) | happy))
+            steps = steps + active.astype(jnp.int32)
         return v, giv, done, steps
 
     v, giv, done, steps = lax.while_loop(cond, body, (v, giv, done, steps))
-    y = jax.vmap(givens.solve)(giv, steps)            # (k, m)
-    dx = jnp.einsum("km,kmn->kn", y, v[:, :m, :n].astype(dtype))
-    x = x0 + vprecond(dx)
+    with jax.named_scope("lsq"):
+        y = jax.vmap(givens.solve)(giv, steps)        # (k, m)
+        dx = jnp.einsum("km,kmn->kn", y, v[:, :m, :n].astype(dtype))
+    with jax.named_scope("precond"):
+        dx = vprecond(dx)
+    with jax.named_scope("lsq"):
+        x = x0 + dx
     return x, steps
 
 
@@ -738,13 +802,20 @@ def gmres_batched_cycle(a, b: jax.Array, x: jax.Array, *, m: int = 30,
     if active is None:
         active = jnp.ones(b.shape[:1], bool)
 
-    r = b - blockmv(x)
-    beta = _lane_norms(r)
+    def resid_of(x):
+        with jax.named_scope("residual"):
+            with jax.named_scope("operator"):
+                ax = blockmv(x)
+            r = b - ax
+            return r, _lane_norms(r)
+
+    r, beta = resid_of(x)
     act = active & (beta > tol_abs)
     x2, inner = _block_cycle(blockmv, vprecond, batched_gs, x, r, beta,
                              m, tol_abs, act, basis_dtype)
-    x = jnp.where(act[:, None], x2, x)
-    beta = _lane_norms(b - blockmv(x))
+    with jax.named_scope("lsq"):
+        x = jnp.where(act[:, None], x2, x)
+    _, beta = resid_of(x)
     return x, beta, inner
 
 
@@ -801,8 +872,11 @@ def gmres_batched(a, b: jax.Array, *, m: int = 30, tol=1e-5,
     max_restarts = jnp.asarray(max_restarts, jnp.int32)
 
     def resid_of(x):
-        r = b - blockmv(x)
-        return r, _lane_norms(r)
+        with jax.named_scope("residual"):
+            with jax.named_scope("operator"):
+                ax = blockmv(x)
+            r = b - ax
+            return r, _lane_norms(r)
 
     x0 = jnp.zeros_like(b)
     r0, beta0 = resid_of(x0)
@@ -817,7 +891,8 @@ def gmres_batched(a, b: jax.Array, *, m: int = 30, tol=1e-5,
         active = (beta > tol_abs) & (kk < max_restarts)
         x2, inner = _block_cycle(blockmv, vprecond, batched_gs, x, r, beta,
                                  m, tol_abs, active, basis_dtype)
-        x = jnp.where(active[:, None], x2, x)
+        with jax.named_scope("lsq"):
+            x = jnp.where(active[:, None], x2, x)
         r, beta = resid_of(x)
         return x, r, beta, kk + active.astype(jnp.int32), steps + inner
 

@@ -18,6 +18,7 @@ the same code path a real XLA `DataLoss`/halt error takes.
 """
 from __future__ import annotations
 
+import collections
 import logging
 import time
 from dataclasses import dataclass, field
@@ -38,12 +39,13 @@ class StragglerMonitor:
         self.window = window
         self.zscore = zscore
         self.min_samples = min_samples
-        self.times: list = []
+        # The last ``window`` step times: the baseline a step is judged by.
+        self.times: collections.deque = collections.deque(maxlen=window)
         self.flagged: list = []
 
     def record(self, step: int, dt: float) -> bool:
         """Returns True if this step is a straggler."""
-        hist = self.times[-self.window:]
+        hist = list(self.times)
         self.times.append(dt)
         if len(hist) < self.min_samples:
             return False

@@ -104,10 +104,18 @@ class SolverHandle:
         # The operator is a jit ARGUMENT, not a closure: a closed-over
         # array would be baked into the program as a literal (268 MB for
         # an 8192^2 f32 matrix), bloating compile time and memory.
-        self._cycle = jax.jit(functools.partial(
-            gmres_batched_cycle, m=int(m), gs=gs, precond=precond,
-            compute_dtype=dtype))
+        cycle = functools.partial(gmres_batched_cycle, m=int(m), gs=gs,
+                                  precond=precond, compute_dtype=dtype)
+
+        def batched_cycle(*args, **kwargs):
+            # Runs only when jit traces the cycle: a count above 1 means a
+            # call missed the compiled executable and recompiled.
+            self.traces += 1
+            return cycle(*args, **kwargs)
+
+        self._cycle = jax.jit(batched_cycle)
         self.cycles_run = 0
+        self.traces = 0
 
     @property
     def n(self) -> int:

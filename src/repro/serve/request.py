@@ -183,3 +183,10 @@ class SolveOutcome:
     restarts: int = 0
     inner_steps: int = 0
     reason: str = ""              # REJECTED: why admission refused it
+    # Seconds on the server's clock from submit to the first placement in
+    # a lane, and from that placement to retirement.  None where the
+    # server did not see the moment: a request refused at admission,
+    # failed from the backlog before any placement, or restored from a
+    # checkpoint.
+    queue_s: Optional[float] = None
+    lane_s: Optional[float] = None

@@ -33,6 +33,13 @@ of it is driven deterministically by ``runtime.faultinject`` sites
 (``serve.cycle``, ``serve.lane_nan``).  ``save_checkpoint`` /
 ``restore_checkpoint`` serialize the lane blocks + scheduler state at a
 tick boundary so a killed server resumes bit-identically.
+
+Tracing: ``submit`` and ``step`` open ``jax.profiler.TraceAnnotation``
+spans named ``repro.serve.*`` (``step`` holds one child per phase:
+admit, pack, dispatch, sync, retire), so a profiler trace puts the host's
+phases on the device ops' clock; outside a trace a span costs about a
+microsecond.  Each outcome carries the seconds its request spent queued
+and in a lane (``SolveOutcome.queue_s`` / ``lane_s``).
 """
 from __future__ import annotations
 
@@ -43,6 +50,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint import checkpoint as ckpt
 from repro.core.recovery import CircuitBreaker
@@ -91,8 +99,10 @@ class SolverServer:
         self._clock = clock
         self._sleep = sleep
         self._next_rid = 0
-        self._t0: Optional[float] = None
-        self._wall: float = 0.0
+        # rid -> submit time and first placement, on ``clock``, until the
+        # request's outcome is recorded.
+        self._submitted: Dict[int, float] = {}
+        self._placed: Dict[int, float] = {}
         # --- fault-handling knobs / state ------------------------------
         self._deadline_default = deadline_default
         self._quarantine_ticks = int(quarantine_ticks)
@@ -142,51 +152,50 @@ class SolverServer:
         """
         rid = self._next_rid
         self._next_rid += 1
-        if deadline_ticks is None:
-            deadline_ticks = self._deadline_default
-        if self.breaker.dead:
-            self.results[rid] = SolveOutcome(
-                rid=rid, status=REJECTED,
-                reason="circuit breaker open: solver handle is failing "
-                       f"({self._last_cycle_error})")
-            return rid
-        try:
-            validate_params(tol, max_restarts, deadline_ticks)
-            arr = validate_b(b, n=self.handle.n,
-                             dtype=self.handle.key.dtype)
-        except AdmissionError as e:
-            self.results[rid] = SolveOutcome(rid=rid, status=REJECTED,
-                                             reason=e.reason)
-            return rid
-        # Quantize the retirement threshold to the handle's compute
-        # dtype: the compiled cycle masks lanes with the downcast
-        # tol_abs, and host retirement must agree on "converged" or a
-        # lane can wedge between the two thresholds (device says done,
-        # host keeps charging restarts until the budget fails it).
-        dt = np.dtype(self.handle.key.dtype)
-        tol_abs = float(np.asarray(float(tol) * np.linalg.norm(arr), dt))
-        req = SolveRequest(rid=rid, b=arr, tol=float(tol),
-                           max_restarts=int(max_restarts),
-                           deadline_ticks=(None if deadline_ticks is None
-                                           else int(deadline_ticks)),
-                           tol_abs_override=tol_abs)
-        if wait:
-            deadline = self._clock() + max_wait
-            while self.ingress.full and self._clock() < deadline:
-                depth = len(self.ingress)
-                self.step()              # we are our own consumer
-                if len(self.ingress) >= depth:
-                    # Tick freed no headroom (lanes mid-solve, backlog
-                    # full): yield real time toward the deadline.
-                    self._sleep(0.01)
-            ok = self.ingress.push(req)
-        else:
-            ok = self.ingress.push(req)
-        if not ok:
-            self.results[rid] = SolveOutcome(
-                rid=rid, status=REJECTED,
-                reason=f"backpressure: queue depth {len(self.ingress)} "
-                       f">= {self.ingress.max_depth}")
+        with TraceAnnotation("repro.serve.submit", rid=rid):
+            t_submit = self._clock()
+            if deadline_ticks is None:
+                deadline_ticks = self._deadline_default
+            if self.breaker.dead:
+                self._finish(rid, REJECTED,
+                             reason="circuit breaker open: solver handle is "
+                                    f"failing ({self._last_cycle_error})")
+                return rid
+            try:
+                validate_params(tol, max_restarts, deadline_ticks)
+                arr = validate_b(b, n=self.handle.n,
+                                 dtype=self.handle.key.dtype)
+            except AdmissionError as e:
+                self._finish(rid, REJECTED, reason=e.reason)
+                return rid
+            # Quantize the retirement threshold to the handle's compute
+            # dtype: the compiled cycle masks lanes with the downcast
+            # tol_abs, and host retirement must agree on "converged" or a
+            # lane can wedge between the two thresholds (device says done,
+            # host keeps charging restarts until the budget fails it).
+            dt = np.dtype(self.handle.key.dtype)
+            tol_abs = float(np.asarray(float(tol) * np.linalg.norm(arr), dt))
+            req = SolveRequest(rid=rid, b=arr, tol=float(tol),
+                               max_restarts=int(max_restarts),
+                               deadline_ticks=(None if deadline_ticks is None
+                                               else int(deadline_ticks)),
+                               tol_abs_override=tol_abs)
+            if wait:
+                deadline = self._clock() + max_wait
+                while self.ingress.full and self._clock() < deadline:
+                    depth = len(self.ingress)
+                    self.step()              # we are our own consumer
+                    if len(self.ingress) >= depth:
+                        # Tick freed no headroom (lanes mid-solve, backlog
+                        # full): yield real time toward the deadline.
+                        self._sleep(0.01)
+            if self.ingress.push(req):
+                self._submitted[rid] = t_submit
+            else:
+                self._finish(
+                    rid, REJECTED,
+                    reason=f"backpressure: queue depth {len(self.ingress)} "
+                           f">= {self.ingress.max_depth}")
         return rid
 
     # ------------------------------------------------------------------
@@ -200,18 +209,30 @@ class SolverServer:
             self.state = st
             self.ingress.pop()
 
-    def _pack(self) -> None:
+    def _pack(self) -> List[tuple]:
+        """Fill idle lanes; returns the placements ``(lane, request)``."""
         self.state, placed = sched.pack(self.state)
-        if not placed:
-            return
         # Row-wise device updates: only the refilled lanes move — the
         # resident lanes' b/x never round-trip through the host.
         dt = self._b.dtype
+        now = self._clock()
         for lane, req in placed:
             self._b = self._b.at[lane].set(jnp.asarray(req.b, dt))
             self._x = self._x.at[lane].set(0.0)
             self._tol_abs[lane] = req.tol_abs
             self._inner[lane] = 0
+            self._placed.setdefault(req.rid, now)
+        return placed
+
+    def _finish(self, rid: int, status: str, **fields) -> None:
+        """Record ``rid``'s outcome, with its queue and lane seconds."""
+        t_submit = self._submitted.pop(rid, None)
+        t_placed = self._placed.pop(rid, None)
+        if t_placed is not None:
+            fields["lane_s"] = self._clock() - t_placed
+            if t_submit is not None:
+                fields["queue_s"] = t_placed - t_submit
+        self.results[rid] = SolveOutcome(rid=rid, status=status, **fields)
 
     def _scrub_lane(self, i: int) -> None:
         """Zero a faulted lane's device rows: NaN in a retired lane's x
@@ -248,9 +269,8 @@ class SolverServer:
             self.state, lanes=tuple(lanes), pending=(),
             retired_failed=self.state.retired_failed + len(retired))
         for r in retired:
-            self.results[r.req.rid] = SolveOutcome(
-                rid=r.req.rid, status=FAILED, residual=float("inf"),
-                restarts=r.restarts, reason=reason)
+            self._finish(r.req.rid, FAILED, residual=float("inf"),
+                         restarts=r.restarts, reason=reason)
         return retired
 
     def step(self) -> List[sched.Retirement]:
@@ -263,31 +283,59 @@ class SolverServer:
         failure; while the breaker cools down, steps admit but run no
         cycle; a DEAD breaker fails the whole backlog (once) instead of
         wedging ``run()``.
+
+        A profiler trace shows the tick as a ``repro.serve.step`` span
+        whose children are its phases: ``admit``, ``pack`` (metadata: the
+        lanes and rids placed), ``dispatch`` (the jitted cycle call, which
+        returns before the device finishes), ``sync`` (the host waiting
+        for the cycle's residuals) and ``retire`` (metadata: the rids
+        retired).
         """
-        if self._t0 is None:
-            self._t0 = self._clock()
+        with TraceAnnotation("repro.serve.step"):
+            return self._tick()
+
+    def _tick(self) -> List[sched.Retirement]:
         t_start = self._clock()
         self._steps += 1
         if self.breaker.dead:
             return self._fail_backlog(
                 f"circuit breaker open permanently ({self._last_cycle_error})")
-        self._admit_from_ingress()
+        with TraceAnnotation("repro.serve.admit"):
+            self._admit_from_ingress()
         if not self.breaker.allow(self._steps):
             self.breaker_skips += 1
             return []
-        self._pack()
+        with TraceAnnotation("repro.serve.pack") as span:
+            placed = self._pack()
+            if placed and span.is_enabled():
+                span.set_metadata(lanes=[lane for lane, _ in placed],
+                                  rids=[req.rid for _, req in placed])
         active = np.array([not ln.idle for ln in self.state.lanes])
         if not active.any():
             return []
+        out = self._run_cycle(active)
+        if out is None:
+            return []
+        with TraceAnnotation("repro.serve.retire") as span:
+            retired = self._collect(*out, active)
+            if retired and span.is_enabled():
+                span.set_metadata(rids=[r.req.rid for r in retired])
+        self.straggler.record(self.state.tick, self._clock() - t_start)
+        return retired
 
+    def _run_cycle(self, active: np.ndarray) -> Optional[tuple]:
+        """Run the lockstep cycle with bounded retries; ``(x, beta,
+        inner)``, or None when the retries ran out."""
         attempt = 0
         while True:
             try:
                 faultinject.check("serve.cycle", index=self.state.tick)
-                x, beta, inner = self.handle.cycle(
-                    self._b, self._x, np.where(active, self._tol_abs, 0.0),
-                    active)
-                beta = np.array(beta)       # materialize: surface faults HERE
+                with TraceAnnotation("repro.serve.dispatch"):
+                    x, beta, inner = self.handle.cycle(
+                        self._b, self._x,
+                        np.where(active, self._tol_abs, 0.0), active)
+                with TraceAnnotation("repro.serve.sync"):
+                    beta = np.array(beta)   # materialize: surface faults HERE
                 break
             except Exception as e:  # noqa: BLE001 — injected + kernel faults
                 self.cycle_faults += 1
@@ -301,9 +349,14 @@ class SolverServer:
                 # and scheduler state untouched — the restart boundary IS
                 # the rollback) and the breaker hears about it.
                 self.breaker.record_failure(self._steps)
-                return []
+                return None
         self.breaker.record_success()
+        return x, beta, inner
 
+    def _collect(self, x, beta: np.ndarray, inner,
+                 active: np.ndarray) -> List[sched.Retirement]:
+        """Take a cycle's results: evict faulted lanes, retire finished
+        ones and copy their answers to the host."""
         if faultinject.fire("serve.lane_nan", index=self.state.tick):
             i = int(np.argmax(active))      # lowest-indexed active lane
             x = x.at[i].set(jnp.nan)
@@ -326,23 +379,19 @@ class SolverServer:
             for i in idx:
                 self._scrub_lane(i)
             for r in failed:
-                self.results[r.req.rid] = SolveOutcome(
-                    rid=r.req.rid, status=FAILED, residual=float("inf"),
-                    restarts=r.restarts, reason=r.reason)
+                self._finish(r.req.rid, FAILED, residual=float("inf"),
+                             restarts=r.restarts, reason=r.reason)
             fault_retired = failed
 
         self.state, retired = sched.retire(self.state, beta)
         if retired:
             x_host = np.asarray(self._x)
             for r in retired:
-                self.results[r.req.rid] = SolveOutcome(
-                    rid=r.req.rid, status=r.status,
-                    x=x_host[r.lane].copy(), residual=r.residual,
-                    restarts=r.restarts,
-                    inner_steps=int(self._inner[r.lane]),
-                    reason=r.reason)
-        self.straggler.record(self.state.tick, self._clock() - t_start)
-        self._wall = self._clock() - self._t0
+                self._finish(r.req.rid, r.status,
+                             x=x_host[r.lane].copy(), residual=r.residual,
+                             restarts=r.restarts,
+                             inner_steps=int(self._inner[r.lane]),
+                             reason=r.reason)
         return fault_retired + retired
 
     def run(self, max_ticks: int = 10_000) -> int:
@@ -488,6 +537,10 @@ class SolverServer:
         for i, meta in enumerate(extra["ingress"]):
             self.ingress.push(self._req_from(meta, tree["ingress_b"][i]))
         self._next_rid = int(extra["next_rid"])
+        # Stamps are on this process's clock and are not saved: restored
+        # requests report no queue or lane seconds.
+        self._submitted.clear()
+        self._placed.clear()
         return self
 
     # ------------------------------------------------------------------
@@ -495,7 +548,8 @@ class SolverServer:
     # ------------------------------------------------------------------
     def metrics(self) -> dict:
         """Scheduler counters + ingress + handle-cache + fault state +
-        throughput."""
+        retirements per tick.  ``cycle_traces`` counts how often the
+        handle's cycle was traced; above 1, a tick recompiled it."""
         m = sched.metrics(self.state)
         m.update({
             "ingress_depth": len(self.ingress),
@@ -507,9 +561,7 @@ class SolverServer:
             "breaker_trips": self.breaker.trips,
             "breaker_skips": self.breaker_skips,
             "straggler_ticks": len(self.straggler.flagged),
-            "wall_s": self._wall,
-            "solves_per_s": ((m["retired_done"] + m["retired_failed"])
-                             / self._wall if self._wall > 0 else 0.0),
+            "cycle_traces": self.handle.traces,
             "retirement_rate": ((m["retired_done"] + m["retired_failed"])
                                 / m["tick"] if m["tick"] else 0.0),
         })

@@ -147,6 +147,16 @@ def test_straggler_monitor():
     assert mon.flagged[0][0] == 20
 
 
+def test_straggler_monitor_keeps_only_its_window():
+    mon = StragglerMonitor(window=8, zscore=3.0, min_samples=5)
+    for i in range(1000):
+        mon.record(i, 0.1 + 0.001 * (i % 3))
+    assert len(mon.times) == 8
+    # A slow step is still judged against the last ``window`` steps.
+    assert mon.record(1000, 1.5) is True
+    assert [f[0] for f in mon.flagged] == [1000]
+
+
 def test_elastic_plan():
     p = plan(512, model_parallel=16, global_batch=256, want_pods=2)
     assert p.mesh_shape == (2, 16, 16)
