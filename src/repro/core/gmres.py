@@ -455,6 +455,31 @@ def _gmres_cycle_pipelined(op, update, x0, r0, beta, m, tol_abs, precond,
     return x, state.steps
 
 
+def _pow2_scale(b, axis_name=None):
+    """A power of two at or above ``max |b|`` (1 for a zero, inf or nan
+    ``b``) and its reciprocal, both built from their bits.
+
+    ``gmres`` solves at this scale: a power of two scales exactly, so a
+    right-hand side in the normal range solves bit for bit as it would
+    unscaled, while one far from 1 (a decayed time-stepper state near
+    1e-17) stays clear of the cycles' absolute breakdown guards
+    (``tiny ** 0.5``) and of squared norms that underflow.
+    """
+    peak = jnp.max(jnp.abs(b))
+    if axis_name is not None:
+        peak = lax.pmax(peak, axis_name)
+    info = jnp.finfo(peak.dtype)
+    bias = info.maxexp - 1
+    bits = jnp.dtype(f"int{8 * info.dtype.itemsize}")
+    _, e = jnp.frexp(peak)
+    e = jnp.clip(e, 2 - bias, bias - 1).astype(bits)   # both stay normal
+
+    def pow2(k):
+        return lax.bitcast_convert_type((k + bias) << info.nmant, peak.dtype)
+
+    return pow2(e), pow2(-e)
+
+
 @f32_matmuls
 def gmres(
     a,
@@ -515,11 +540,16 @@ def gmres(
 
     Returns GmresResult; residual is the TRUE residual recomputed from x,
     ``diagnostics`` the cycle-level health report (see ``Diagnostics``).
+    The solve runs on ``b`` and ``x0`` divided by a power of two near
+    ``max |b|`` (``_pow2_scale``), so a tiny or huge ``b`` converges as
+    its unit-scale copy would.
     """
     matvec = as_operator(a)
     if x0 is None:
         x0 = jnp.zeros_like(b)
     check_precond(precond)
+    scale, inv = _pow2_scale(b, axis_name)
+    b, x0 = b * inv, x0 * inv
     identity_precond = (precond is None
                         or getattr(precond, "is_identity", False))
     if precond is None:
@@ -579,12 +609,13 @@ def gmres(
     converged = beta <= tol_abs
     diags = Diagnostics(
         status=classify_residuals(hist, converged=converged),
-        residual_history=hist,
+        residual_history=hist * scale,
         history_len=jnp.minimum(k + 1, history).astype(jnp.int32),
     )
     return GmresResult(
-        x=x, residual=beta, restarts=k, converged=converged, inner_steps=steps,
-        done=converged | (k >= max_restarts), diagnostics=diags,
+        x=x * scale, residual=beta * scale, restarts=k, converged=converged,
+        inner_steps=steps, done=converged | (k >= max_restarts),
+        diagnostics=diags,
     )
 
 

@@ -559,7 +559,8 @@ class BandedILU0Preconditioner(Preconditioner):
     flops, O(bands) live state).  Apply is two banded triangular sweeps
     (unit-lower forward, upper backward) through the
     ``kernels/trisolve.banded_trisweep`` kernel on the standard
-    compiled/interpret/ref dispatch (``tuning.trisweep_fits``).
+    compiled/interpret/ref dispatch (``trisolve`` decides from the
+    offsets and the vector; ``tuning.trisweep_fits`` is its VMEM gate).
 
     ``pattern`` restricts the factorization to a subset of the operator's
     offsets: ``pattern=(-1, 0, 1)`` is LINE-JACOBI — ILU(0) of the
@@ -590,14 +591,22 @@ class BandedILU0Preconditioner(Preconditioner):
         self.pattern = pattern
         (self.l_bands, self.l_offsets,
          self.u_bands, self.u_offsets) = trisolve.banded_ilu0(bands, offsets)
+        # The sweep kernel's lane-dense copies of the factors (None where
+        # it cannot take them), built here so no apply re-lays the bands.
+        self.l_tiles = trisolve.kernel_tiles(self.l_bands, self.l_offsets,
+                                             unit_diag=True)
+        self.u_tiles = trisolve.kernel_tiles(self.u_bands, self.u_offsets,
+                                             unit_diag=False)
         self.n = int(bands.shape[1])
 
     def _sweeps(self, v):
         from repro.kernels import trisolve
         z = trisolve.banded_trisweep(self.l_bands, v, self.l_offsets,
-                                     unit_diag=True, lower=True)
+                                     unit_diag=True, lower=True,
+                                     tiles=self.l_tiles)
         return trisolve.banded_trisweep(self.u_bands, z, self.u_offsets,
-                                        unit_diag=False, lower=False)
+                                        unit_diag=False, lower=False,
+                                        tiles=self.u_tiles)
 
     def __call__(self, v):
         return self._sweeps(v)

@@ -18,20 +18,30 @@ its ``line_jacobi`` / ``banded_block_jacobi`` restrictions).  Two pieces:
 
 ``banded_trisweep(bands, v, offsets, unit_diag=, lower=)`` — the APPLY.
   Solves the banded triangular system (unit-lower forward substitution or
-  upper backward substitution).  Dispatch follows the standard kernel
-  policy (``tuning.kernel_mode`` x ``tuning.trisweep_fits``): the Pallas
-  kernel walks sequential row blocks on a (nb,) grid with the trailing K
-  solved entries carried in a VMEM scratch ring — one HBM read of bands/v
-  and one write of z — and ``banded_trisweep_ref`` is the psum-safe
-  blocked jnp oracle (also the vmapped multi-RHS path: substitution is
-  sequential in rows but embarrassingly parallel across lanes).
+  upper backward substitution).  The Pallas kernel is line-blocked and
+  lane-dense: v, z and every band travel as (rows, 128) arrays (a free
+  reshape when n is a multiple of 1024, else one pad), and the rows go in
+  blocks of 1024, one f32 (8, 128) vreg, several blocks a grid step.  A
+  band that reaches a whole number of blocks is one aligned multiply-add
+  against a block solved earlier and kept in a VMEM scratch ring; the +-1
+  chain inside a block is a first-order linear recurrence, solved by a
+  10-level Hillis-Steele scan of affine maps built from lane and sublane
+  rolls.  The upper sweep is the same kernel walking the blocks in
+  reverse (a reversed ``index_map``, mirrored rolls); nothing is flipped.
+  One HBM read of bands and v, one write of z.
 
-  An UPPER solve is a lower solve read back-to-front: flip bands/v along
-  the row axis, negate the offsets, forward-substitute, flip the result.
-  Both directions therefore share one kernel and one reference.
+  Dispatch is decided at trace time from what the call shows: the kernel
+  runs where the mode allows kernels (``tuning.kernel_mode``), v is one
+  vector, and every band other than the diagonal and +-1 sits a multiple
+  of 1024 rows away (a 2-D stencil on a grid 1024 wide, or no far band at
+  all, as ``line_jacobi``); ``tuning.trisweep_fits`` is the VMEM gate.
+  Everything else takes ``banded_trisweep_ref``, the blocked jnp oracle
+  (also the vmapped multi-RHS path), which reads the upper sweep as a
+  lower one on flipped bands.  ``DISPATCH`` counts each choice.
 """
 from __future__ import annotations
 
+import collections
 import functools
 
 import jax
@@ -41,7 +51,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import tuning
-from repro.kernels.tuning import _round_up
 
 
 def _mask_oob(bands, offsets):
@@ -214,108 +223,221 @@ def _check_tri(bands, v, offsets, unit_diag, lower):
 
 
 # --------------------------------------------------------------------------
-# Triangular sweep: Pallas kernel
+# Triangular sweep: line-blocked Pallas kernel
 # --------------------------------------------------------------------------
-def _trisweep_kernel(b_ref, v_ref, o_ref, zp_ref, *,
-                     offsets, unit_diag, k_ring, bm):
-    """Sequential row blocks, one matrix row per SUBLANE: b_ref is the
-    (bm, nbands) tile, v_ref/o_ref (bm, 1), and zp_ref (k_ring + bm, 1)
-    carries the trailing k_ring solved entries across blocks (requires
-    bm >= k_ring).  Rows on sublanes make every per-row access a dynamic
-    sublane offset, which Mosaic lowers; a dynamic LANE offset it refuses.
+LANE = tuning.LANE
+SUB = 8                     # f32 sublanes: a block is one (8, 128) vreg
+BLOCK = SUB * LANE          # 1024 rows, flattened row-major
+LEVELS = BLOCK.bit_length() - 1
+
+# Trace-time record of ``banded_trisweep``'s choices: ``("kernel", why)``
+# or ``("ref", why)`` -> number of sweeps traced that way.
+DISPATCH = collections.Counter()
+
+
+def _flat_shift(x, d, forward, lane):
+    """Shift a block along its 1024 flattened rows: element i takes
+    element i - d (``forward``) or i + d.  ``d`` is below 128 (a lane
+    roll, the wrapped lanes taken from the neighbouring sublane) or a
+    multiple of 128 (a sublane roll).  The d elements with no source hold
+    wrapped values; callers mask them."""
+    q, r = divmod(d, LANE)
+    if not r:
+        return pltpu.roll(x, q if forward else SUB - q, 0)
+    if forward:
+        y = pltpu.roll(x, r, 1)
+        return jnp.where(lane >= r, y, pltpu.roll(y, 1, 0))
+    y = pltpu.roll(x, LANE - r, 1)
+    return jnp.where(lane < LANE - r, y, pltpu.roll(y, SUB - 1, 0))
+
+
+def _sweep_kernel(b_ref, v_ref, o_ref, zs_ref, *, offsets, unit_diag,
+                  forward, blocks, ring):
+    """``blocks`` blocks of 1024 rows per grid step, solved in sweep order.
+
+    zs_ref holds solved blocks in sweep order: the first ``ring`` are the
+    last blocks of the step before, then this step's.  A far band of
+    reach m blocks reads the block solved m before, whole and aligned; the
+    +-1 chain ``z_i = a_i z_(i-1) + w_i`` inside a block is a Hillis-Steele
+    scan of the affine maps ``(a_i, w_i)``, its first map carrying the
+    boundary row of the block solved just before.
     """
-    i = pl.program_id(0)
-    acc = o_ref.dtype
-    idx0 = offsets.index(0) if 0 in offsets else None
+    g = pl.program_id(0)
+    idx = {off: d for d, off in enumerate(offsets)}
+    near = idx.get(-1 if forward else 1)
+    far = [(d, abs(off) // BLOCK) for d, off in enumerate(offsets)
+           if abs(off) > 1]
+    sub = lax.broadcasted_iota(jnp.int32, (SUB, LANE), 0)
+    lane = lax.broadcasted_iota(jnp.int32, (SUB, LANE), 1)
+    pos = sub * LANE + lane if forward else BLOCK - 1 - (sub * LANE + lane)
 
-    @pl.when(i == 0)
+    @pl.when(g == 0)
     def _seed():
-        zp_ref[...] = jnp.zeros_like(zp_ref)
+        zs_ref[...] = jnp.zeros_like(zs_ref)
 
-    def row(r, carry):
-        z = v_ref[pl.ds(r, 1), :].astype(acc)
-        for d, off in enumerate(offsets):
-            if off < 0:
-                coef = b_ref[pl.ds(r, 1), pl.ds(d, 1)].astype(acc)
-                z = z - coef * zp_ref[pl.ds(r + k_ring + off, 1), :]
+    def solved(u):
+        return pl.ds(pl.multiple_of((ring + u) * SUB, SUB), SUB)
+
+    def block(u, carry):
+        t = u if forward else blocks - 1 - u
+        rows = pl.ds(pl.multiple_of(t * SUB, SUB), SUB)
+        w = v_ref[rows, :]
+        for d, m in far:
+            w = w - b_ref[d, rows, :] * zs_ref[solved(u - m), :]
+        a = None if near is None else -b_ref[near, rows, :]
         if not unit_diag:
-            z = z / b_ref[pl.ds(r, 1), pl.ds(idx0, 1)].astype(acc)
-        zp_ref[pl.ds(k_ring + r, 1), :] = z
-        o_ref[pl.ds(r, 1), :] = z
+            diag = b_ref[idx[0], rows, :]
+            w = w / diag
+            a = None if a is None else a / diag
+        if a is not None:
+            # pos counts rows in sweep order, so pos == 0 is the block's
+            # first row to solve: fold the boundary row in there.
+            z_in = _flat_shift(zs_ref[solved(u - 1), :], 1, forward, lane)
+            w = jnp.where(pos == 0, w + a * z_in, w)
+            p = jnp.where(pos == 0, 0.0, a)
+            for lvl in range(LEVELS):
+                s = 1 << lvl
+                keep = pos < s
+                qs = _flat_shift(w, s, forward, lane)
+                if lvl + 1 < LEVELS:
+                    ps = _flat_shift(p, s, forward, lane)
+                w = jnp.where(keep, w, p * qs + w)
+                if lvl + 1 < LEVELS:
+                    p = jnp.where(keep, p, p * ps)
+        zs_ref[solved(u), :] = w
+        o_ref[rows, :] = w
         return carry
 
-    lax.fori_loop(0, bm, row, 0, unroll=False)
-    # Shift the trailing solved entries to the front for the next block.
-    zp_ref[:k_ring, :] = zp_ref[bm:bm + k_ring, :]
+    lax.fori_loop(0, blocks, block, 0)
+    zs_ref[:ring * SUB, :] = zs_ref[blocks * SUB:(blocks + ring) * SUB, :]
+
+
+def _unfit(bands, offsets, dtype, ndim: int = 1) -> str | None:
+    """Why the kernel cannot take these bands, or None: it needs one
+    vector (``ndim`` 1), at least one band, f32 arithmetic, and every band
+    other than +-1 and the diagonal a whole number of 1024-row blocks
+    away."""
+    if ndim != 1:
+        return "batched"
+    if not bands.size:
+        return "no_bands"
+    if jnp.promote_types(dtype, jnp.float32) != jnp.float32:
+        return "dtype"
+    if any(abs(o) > 1 and abs(o) % BLOCK for o in offsets):
+        return "far_band_unaligned"
+    return None
+
+
+def _ring(offsets) -> int:
+    """Solved blocks carried between grid steps: the deepest far reach,
+    and at least the one block the +-1 chain continues from."""
+    return max(max((abs(o) // BLOCK for o in offsets), default=0), 1)
+
+
+def _lane_dense(bands, offsets, unit_diag):
+    nbands, n = bands.shape
+    n_pad = -(-n // BLOCK) * BLOCK
+    tiles = bands.astype(jnp.float32)
+    if n_pad != n:
+        # Padded rows solve to 0: zero bands, v = 0, a unit diagonal.
+        tiles = jnp.pad(tiles, ((0, 0), (0, n_pad - n)))
+        if not unit_diag:
+            tiles = tiles.at[offsets.index(0), n:].set(1.0)
+    return tiles.reshape(nbands, n_pad // LANE, LANE)
+
+
+def kernel_tiles(bands: jax.Array, offsets: tuple, *,
+                 unit_diag: bool) -> jax.Array | None:
+    """The kernel's band layout: f32 ``(nbands, rows, 128)``, padded to
+    whole 1024-row blocks; None where the kernel cannot take these bands.
+
+    A preconditioner builds it once, at factor time, and passes it to
+    ``banded_trisweep`` as ``tiles=``: a (nbands, n) array does not share
+    the tiled HBM layout of its lane-dense reshape, so deriving the tiles
+    inside every sweep would copy the bands each time."""
+    offsets = tuple(int(o) for o in offsets)
+    if _unfit(bands, offsets, bands.dtype):
+        return None
+    return _lane_dense(bands, offsets, unit_diag)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("offsets", "unit_diag", "lower", "block_m",
-                              "interpret"))
+    jax.jit, static_argnames=("offsets", "unit_diag", "lower", "interpret"))
 def banded_trisweep_kernel(bands: jax.Array, v: jax.Array, offsets: tuple, *,
                            unit_diag: bool, lower: bool,
-                           block_m: int = 0,
                            interpret: bool = False) -> jax.Array:
-    """One-pass Pallas triangular sweep (see module docstring)."""
+    """Line-blocked Pallas triangular sweep (see module docstring).
+
+    ``bands`` is the (nbands, n) band stack or its ``kernel_tiles``."""
     offsets = tuple(int(o) for o in offsets)
-    _check_tri(bands, v, offsets, unit_diag, lower)
-    if not lower:
-        # Same back-to-front reduction as the reference: one kernel serves
-        # both sweep directions.
-        z = banded_trisweep_kernel(
-            bands[:, ::-1] if bands.size else bands, v[::-1],
-            tuple(-o for o in offsets), unit_diag=unit_diag, lower=True,
-            block_m=block_m, interpret=interpret)
-        return z[::-1]
-
     n = v.shape[0]
+    nbands = len(offsets)
+    nblk = -(-n // BLOCK)
     out_dtype = jnp.promote_types(bands.dtype, v.dtype)
-    acc = jnp.promote_types(out_dtype, jnp.float32)
-    k_ring = max((-o for o in offsets), default=0) or 1
-    bm = block_m or tuning.choose_trisweep_block(n, k_ring)
-    bm = max(_round_up(bm, 8), _round_up(k_ring, 8))   # carry needs bm>=K
-    n_pad = _round_up(n, bm)
-    nb = max(len(offsets), 1)
-
-    # Padded tail rows solve to v = 0: identity diagonal, zero off-diags.
-    bands_p = jnp.pad(bands.astype(acc), ((0, 0), (0, n_pad - n)))
-    if not unit_diag:
-        pad_diag = jnp.arange(n_pad) >= n
-        d0 = offsets.index(0)
-        bands_p = bands_p.at[d0].set(jnp.where(pad_diag, 1.0, bands_p[d0]))
-    if not bands.size:
-        bands_p = jnp.zeros((1, n_pad), acc)
-    v_p = jnp.pad(v.astype(acc), (0, n_pad - n))[:, None]
+    why = _unfit(bands, offsets, out_dtype, v.ndim)
+    if why is not None:
+        raise ValueError(f"banded_trisweep_kernel: cannot take offsets "
+                         f"{offsets} on v {v.shape} ({why})")
+    if bands.ndim == 2:
+        _check_tri(bands, v, offsets, unit_diag, lower)
+        bands = _lane_dense(bands, offsets, unit_diag)
+    elif bands.shape != (nbands, nblk * SUB, LANE):
+        raise TypeError(f"banded_trisweep_kernel: tiles {bands.shape} for "
+                        f"{nbands} bands of {n} rows")
+    f32 = jnp.float32
+    ring = _ring(offsets)
+    blocks = tuning.choose_trisweep_blocks(nblk, nbands, ring)
+    steps = nblk // blocks
+    rows = nblk * SUB
+    v_p = jnp.pad(v.astype(f32), (0, nblk * BLOCK - n))
+    if lower:
+        tile = lambda g: (g, 0)
+    else:
+        tile = lambda g: (steps - 1 - g, 0)
 
     z = pl.pallas_call(
-        functools.partial(_trisweep_kernel, offsets=offsets,
-                          unit_diag=unit_diag, k_ring=k_ring, bm=bm),
-        grid=(n_pad // bm,),
+        functools.partial(_sweep_kernel, offsets=offsets,
+                          unit_diag=unit_diag, forward=lower, blocks=blocks,
+                          ring=ring),
+        grid=(steps,),
         in_specs=[
-            pl.BlockSpec((bm, nb), lambda i: (i, 0)),
-            pl.BlockSpec((bm, 1), lambda i: (i, 0)),
+            pl.BlockSpec((nbands, blocks * SUB, LANE),
+                         lambda g: (0,) + tile(g)),
+            pl.BlockSpec((blocks * SUB, LANE), tile),
         ],
-        out_specs=pl.BlockSpec((bm, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, 1), acc),
-        scratch_shapes=[pltpu.VMEM((k_ring + bm, 1), acc)],
+        out_specs=pl.BlockSpec((blocks * SUB, LANE), tile),
+        out_shape=jax.ShapeDtypeStruct((rows, LANE), f32),
+        scratch_shapes=[pltpu.VMEM(((ring + blocks) * SUB, LANE), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="gmres_precond_trisweep",
-    )(bands_p.T, v_p)
-    return z[:n, 0].astype(out_dtype)
+    )(bands, v_p.reshape(rows, LANE))
+    return z.reshape(rows * LANE)[:n].astype(out_dtype)
 
 
 def banded_trisweep(bands: jax.Array, v: jax.Array, offsets: tuple, *,
-                    unit_diag: bool, lower: bool) -> jax.Array:
-    """Dispatching entry point: kernel when the mode and VMEM footprint
-    allow it, ``banded_trisweep_ref`` otherwise (identical results — the
-    sweep is the same sequential recurrence either way)."""
+                    unit_diag: bool, lower: bool,
+                    tiles: jax.Array | None = None) -> jax.Array:
+    """Dispatching entry point: the line-blocked kernel where the mode
+    allows kernels, v is one vector and ``_unfit`` and the VMEM gate find
+    nothing; ``banded_trisweep_ref`` otherwise (the same recurrence, in
+    another association order).  ``tiles``: the bands' ``kernel_tiles``,
+    if the caller keeps them.  Each choice is counted in ``DISPATCH``
+    when it is traced."""
     offsets = tuple(int(o) for o in offsets)
     mode = tuning.kernel_mode()
-    k_ring = max((-o if lower else o for o in offsets), default=0) or 1
-    if (mode == "ref" or v.ndim != 1
-            or not tuning.trisweep_fits(v.shape[0], max(bands.shape[0], 1),
-                                        bands.dtype, k=k_ring)):
+    why = "mode" if mode == "ref" else _unfit(
+        bands, offsets, jnp.promote_types(bands.dtype, v.dtype), v.ndim)
+    if why is None and not tuning.trisweep_fits(len(offsets), _ring(offsets)):
+        why = "vmem"
+    if why is not None:
+        DISPATCH["ref", why] += 1
         return banded_trisweep_ref(bands, v, offsets,
                                    unit_diag=unit_diag, lower=lower)
-    return banded_trisweep_kernel(bands, v, offsets, unit_diag=unit_diag,
-                                  lower=lower, interpret=mode == "interpret")
+    _check_tri(bands, v, offsets, unit_diag, lower)
+    far = any(abs(o) > 1 for o in offsets)
+    DISPATCH["kernel", "far_band" if far else "no_far_band"] += 1
+    return banded_trisweep_kernel(bands if tiles is None else tiles, v,
+                                  offsets, unit_diag=unit_diag, lower=lower,
+                                  interpret=mode == "interpret")

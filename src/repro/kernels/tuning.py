@@ -707,42 +707,41 @@ def cheb_fits(n: int, nbands: int, dtype, *, halo: int = 0,
     return need <= budget
 
 
-def _trisweep_vmem(k: int, bm: int) -> int:
-    """VMEM the triangular-sweep kernel allocates at ``bm`` rows per grid
-    step.  One matrix row per sublane, so every tile pads its lane dim to
-    128: the (bm, nbands) f32 bands tile and the (bm, 1) v / z tiles, each
-    double-buffered, plus the (k + bm, 1) f32 carry ring."""
-    return 6 * vmem_bytes(bm, 1, jnp.float32) + vmem_bytes(k + bm, 1,
-                                                          jnp.float32)
+def _trisweep_vmem(nbands: int, ring: int, blocks: int) -> int:
+    """VMEM the line-blocked triangular sweep allocates at ``blocks``
+    blocks of 1024 rows per grid step, all lane-dense f32: the (nbands,
+    8 blocks, 128) band tile and the (8 blocks, 128) v and z tiles, each
+    double-buffered, and the scratch of ``ring + blocks`` solved blocks."""
+    f32 = jnp.float32
+    return (2 * (nbands + 2) * vmem_bytes(8 * blocks, LANE, f32)
+            + vmem_bytes(8 * (ring + blocks), LANE, f32))
 
 
 @persistent_choice
-def choose_trisweep_block(n: int, k: int = 1,
-                          budget: int = VMEM_BUDGET) -> int:
-    """Row-block size for the banded triangular-sweep kernel.
+def choose_trisweep_blocks(nblk: int, nbands: int, ring: int = 1,
+                           budget: int = VMEM_BUDGET) -> int:
+    """Blocks of 1024 rows per grid step of the triangular sweep.
 
-    The sweep is sequential in rows, so the block only sizes the VMEM
-    tiles — bigger blocks amortize grid overhead; the floor is the carry
-    depth k (the shift ``zp[:k] = zp[bm:bm+k]`` needs bm >= k).
+    The sweep is sequential in blocks, so this only amortizes the grid
+    step's overhead: the largest divisor of the ``nblk`` blocks, up to
+    128, whose tiles fit the budget.
     """
-    floor = _round_up(max(k, 8), 8)
-    best = floor
-    for bm in (128, 256, 512, 1024, 2048, 4096):
-        if bm >= floor and _trisweep_vmem(k, bm) <= budget:
-            best = bm
-    return min(best, max(_round_up(n, 8), floor))
+    for blocks in range(min(nblk, 128), 1, -1):
+        if nblk % blocks == 0 and _trisweep_vmem(nbands, ring,
+                                                 blocks) <= budget:
+            return blocks
+    return 1
 
 
-def trisweep_fits(n: int, nbands: int, dtype, *, k: int = 1,
+def trisweep_fits(nbands: int, ring: int = 1,
                   budget: int = VMEM_BUDGET) -> bool:
-    """Can the triangular-sweep kernel hold a row block + carry ring?
+    """Can the triangular-sweep kernel hold one block and its ring?
 
-    The EXPLICIT dispatch gate for ``kernels/trisolve.banded_trisweep`` —
+    The EXPLICIT VMEM gate for ``kernels/trisolve.banded_trisweep`` —
     overflow (and tests forcing it) degrades to the blocked jnp reference,
-    which computes the identical recurrence.
+    which computes the same recurrence.
     """
-    del n, nbands, dtype  # the kernel tiles rows in f32 whatever the size
-    return _trisweep_vmem(k, _round_up(max(k, 8), 8)) <= budget
+    return _trisweep_vmem(nbands, ring, 1) <= budget
 
 
 def ell_powers_fits(n: int, width: int, dtype, s: int,

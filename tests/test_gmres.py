@@ -39,6 +39,22 @@ def test_converges_all_schemes(gs, kind):
     assert relres(a, res.x, b) < 5 * tol
 
 
+@pytest.mark.parametrize("gs", ["cgs2", "cgs2_pipelined"])
+@pytest.mark.parametrize("exp", [-70, 60])
+def test_tiny_and_huge_rhs_solve_as_unit_scale(gs, exp):
+    """A right-hand side scaled by a power of two far from 1 (a decayed
+    time-stepper state) takes the same steps and returns the unit-scale
+    answer scaled exactly: no absolute guard or squared norm underflows."""
+    a, b = _system(kind="convdiff")
+    kw = dict(m=30, tol=1e-5, gs=gs, max_restarts=50)
+    ref = gmres(a, b, **kw)
+    res = gmres(a, b * 2.0 ** exp, **kw)
+    assert bool(res.converged)
+    assert int(res.inner_steps) == int(ref.inner_steps)
+    np.testing.assert_array_equal(np.asarray(res.x),
+                                  np.asarray(ref.x) * 2.0 ** exp)
+
+
 def test_matches_dense_solve():
     a, b = _system()
     res = gmres(a, b, m=40, tol=1e-6, max_restarts=100)

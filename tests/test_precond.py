@@ -24,6 +24,8 @@ The 4-fake-device sharded composition (halo-exchange Chebyshev, shard-
 local banded block-Jacobi, one-psum-per-step pipelined HLO) runs in a
 subprocess, same pattern as tests/test_distributed.py.
 """
+import collections
+import importlib.util
 import inspect
 import json
 import os
@@ -114,16 +116,34 @@ def test_ilu0_pentadiagonal_residual_small():
     assert np.abs(resid).max() < 0.5
 
 
-@pytest.mark.parametrize("reach", [2, 16])   # 16: a 2-D stencil's -nx band
+def _tri_bands(key, n, offs):
+    """Random triangular bands, a dominant diagonal where offs holds 0,
+    out-of-range entries zeroed."""
+    bands = jax.random.uniform(key, (len(offs), n), minval=0.2, maxval=1.0)
+    if 0 in offs:
+        bands = bands.at[offs.index(0)].add(2.0)
+    return trisolve._mask_oob(bands, offs)
+
+
+def _dense_tri(bands, offs, n, unit):
+    a = np.eye(n) if unit else np.zeros((n, n))
+    rows = np.arange(n)
+    for d, off in enumerate(offs):
+        keep = (rows + off >= 0) & (rows + off < n)
+        a[rows[keep], rows[keep] + off] = np.asarray(bands[d],
+                                                     np.float64)[keep]
+    return a
+
+
+@pytest.mark.parametrize("ny", [4, 9])      # grid lines 1024 wide
 @pytest.mark.parametrize("lower,unit", [(True, True), (True, False),
                                         (False, False)])
-def test_trisweep_kernel_matches_ref(lower, unit, reach):
-    key = jax.random.PRNGKey(7)
-    n = 200
-    offs = (-reach, -1, 0) if lower else (0, 1, reach)
-    bands = jax.random.uniform(key, (3, n), minval=0.2, maxval=1.0)
-    bands = bands.at[offs.index(0)].add(2.0)
-    bands = trisolve._mask_oob(bands, offs)
+def test_trisweep_kernel_matches_ref(lower, unit, ny):
+    n = 1024 * ny
+    offs = (-1024, -1, 0) if lower else (0, 1, 1024)
+    if unit:
+        offs = offs[:-1]
+    bands = _tri_bands(jax.random.PRNGKey(7), n, offs)
     v = jax.random.normal(jax.random.PRNGKey(8), (n,))
     ref = trisolve.banded_trisweep_ref(bands, v, offs, unit_diag=unit,
                                        lower=lower)
@@ -131,6 +151,58 @@ def test_trisweep_kernel_matches_ref(lower, unit, reach):
                                           lower=lower, interpret=True)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("near", [True, False])
+@pytest.mark.parametrize("lower,unit", [(True, True), (True, False),
+                                        (False, False)])
+def test_trisweep_kernel_matches_dense_f64(lower, unit, near):
+    """A ragged n (2.5 blocks): the kernel against the reference and a
+    float64 dense triangular solve, with and without the +-1 band."""
+    n = 2560
+    offs = (-1024, -1, 0) if lower else (0, 1, 1024)
+    offs = tuple(o for o in offs
+                 if (near or abs(o) != 1) and (not unit or o != 0))
+    bands = _tri_bands(jax.random.PRNGKey(9), n, offs)
+    v = jax.random.normal(jax.random.PRNGKey(10), (n,))
+    ker = np.asarray(trisolve.banded_trisweep_kernel(
+        bands, v, offs, unit_diag=unit, lower=lower, interpret=True))
+    ref = trisolve.banded_trisweep_ref(bands, v, offs, unit_diag=unit,
+                                       lower=lower)
+    exact = np.linalg.solve(_dense_tri(bands, offs, n, unit),
+                            np.asarray(v, np.float64))
+    np.testing.assert_allclose(ker, np.asarray(ref), rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(ker, exact, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("lower", [True, False])
+def test_trisweep_kernel_no_far_band(lower):
+    """line_jacobi's factors: only the +-1 band, so any n engages."""
+    n = 3000
+    offs = (-1,) if lower else (0, 1)
+    bands = _tri_bands(jax.random.PRNGKey(11), n, offs)
+    v = jax.random.normal(jax.random.PRNGKey(12), (n,))
+    ker = trisolve.banded_trisweep_kernel(bands, v, offs, unit_diag=lower,
+                                          lower=lower, interpret=True)
+    exact = np.linalg.solve(_dense_tri(bands, offs, n, lower),
+                            np.asarray(v, np.float64))
+    np.testing.assert_allclose(np.asarray(ker), exact, rtol=2e-5,
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("reach", [2, 16, 200])
+def test_trisweep_dispatch_unaligned_far_band_takes_ref(reach):
+    n = 600
+    offs = (-reach, -1)
+    bands = _tri_bands(jax.random.PRNGKey(13), n, offs)
+    v = jax.random.normal(jax.random.PRNGKey(14), (n,))
+    before = collections.Counter(trisolve.DISPATCH)
+    z = trisolve.banded_trisweep(bands, v, offs, unit_diag=True, lower=True)
+    got = trisolve.DISPATCH - before
+    assert got == collections.Counter({("ref", "far_band_unaligned"): 1})
+    ref = trisolve.banded_trisweep_ref(bands, v, offs, unit_diag=True,
+                                       lower=True)
+    np.testing.assert_array_equal(np.asarray(z), np.asarray(ref))
 
 
 def test_fused_cheb_kernel_matches_recurrence():
@@ -366,8 +438,8 @@ def test_cheb_kernel_engages_and_overflow_degrades(monkeypatch):
 
 
 def test_trisweep_kernel_engages_and_overflow_degrades(monkeypatch):
-    op = stencils.poisson_2d(8)
-    b = jnp.sin(jnp.arange(64) * 0.37)
+    op = stencils.poisson_2d(1024, 4)       # four grid lines 1024 wide
+    b = jnp.sin(jnp.arange(4096) * 0.37)
     calls = []
     orig = trisolve.banded_trisweep_kernel
 
@@ -386,11 +458,49 @@ def test_trisweep_kernel_engages_and_overflow_degrades(monkeypatch):
 
     monkeypatch.setattr(trisolve, "banded_trisweep_kernel", boom)
     monkeypatch.setattr(tuning, "trisweep_fits", lambda *a, **k: False)
+    before = collections.Counter(trisolve.DISPATCH)
     res_r = gmres(op, b, m=16, tol=1e-5, max_restarts=60,
                   precond=P.banded_ilu0(op))
+    assert (trisolve.DISPATCH - before).keys() == {("ref", "vmem")}
     assert bool(res_r.converged)
     assert _rel_err(res_r.x, res_k.x) < 1e-4
     assert int(res_r.restarts) == int(res_k.restarts)
+
+
+def _bench_stencil():
+    path = os.path.join(os.path.dirname(__file__), "..", "bench", "configs",
+                        "banded_stencil.py")
+    spec = importlib.util.spec_from_file_location("banded_stencil", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_ilu0_kernel_parity_on_convection_diffusion_steps():
+    """The benchmark's backward-Euler convection-diffusion bands on a grid
+    1024 wide: the ILU(0) apply through the kernel matches the reference,
+    and a solve through each takes the same steps and restarts."""
+    fam = _bench_stencil()
+    cfg = {"nx": 1024, "ny": 8, "beta": [0.5, 0.25], "tau": 4.0}
+    op = fam.program_operator(cfg, {"bands": fam._bands(cfg)})
+    b = fam.smooth_fields({**cfg, "initial": {"modes": 8, "pattern": 0}},
+                          jax.random.PRNGKey(0), 1)[0]
+    pc = P.banded_ilu0(op)
+    before = collections.Counter(trisolve.DISPATCH)
+    z_k = pc(b)
+    assert (trisolve.DISPATCH - before) == collections.Counter(
+        {("kernel", "far_band"): 2})
+    with tuning.force_kernel_mode("ref"):
+        z_r = pc(b)
+    np.testing.assert_allclose(np.asarray(z_k), np.asarray(z_r), rtol=1e-5,
+                               atol=1e-5 * float(jnp.max(jnp.abs(z_r))))
+    kw = dict(m=30, tol=1e-5, max_restarts=50, gs="cgs2_pipelined")
+    res_k = gmres(op, b, b, precond=P.banded_ilu0(op), **kw)
+    with tuning.force_kernel_mode("ref"):
+        res_r = gmres(op, b, b, precond=P.banded_ilu0(op), **kw)
+    assert bool(res_k.converged) and bool(res_r.converged)
+    assert int(res_k.inner_steps) == int(res_r.inner_steps)
+    assert int(res_k.restarts) == int(res_r.restarts)
 
 
 def test_ell_powers_engages_and_overflow_degrades(monkeypatch):

@@ -15,7 +15,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import gmres
 from repro.core.gmres import gmres_batched_cycle
 from repro.core.operators import BandedOperator, DenseOperator
-from repro.kernels import cgs2, tuning
+from repro.kernels import cgs2, trisolve, tuning
 
 
 @pytest.fixture(scope="module")
@@ -87,6 +87,23 @@ def test_banded_spmv_stencil_1024(one_chip, no_compile_cache):
         _f32((5, n), one_chip), _f32((n,), one_chip))
     assert "tpu_custom_call" in text
     assert "gmres_spmv_banded" in text
+
+
+@pytest.mark.parametrize("lower", [True, False])
+def test_ilu0_trisweep_stencil_1024(lower, one_chip, no_compile_cache):
+    """Both sweeps of the 1024^2 stencil's ILU(0), from the lane-dense
+    factors a preconditioner keeps: the kernel, and no copy of the bands."""
+    nx = 1024
+    n = nx * nx
+    offsets = (-nx, -1) if lower else (0, 1, nx)
+    text = _kernel_text(
+        lambda bands, tiles, v: trisolve.banded_trisweep(
+            bands, v, offsets, unit_diag=lower, lower=lower, tiles=tiles),
+        _f32((len(offsets), n), one_chip),
+        _f32((len(offsets), n // 128, 128), one_chip),
+        _f32((n,), one_chip))
+    assert "gmres_precond_trisweep" in text
+    assert " copy(" not in text
 
 
 def test_cgs2_payload_kernel_2p20(one_chip, no_compile_cache):
