@@ -83,6 +83,15 @@ def program_operator(cfg: dict, arrays: dict):
     return BandedOperator(arrays["bands"], offsets(cfg), "pallas")
 
 
+def shard_specs(cfg: dict, axis: str) -> dict:
+    """How a cell on several chips places ``arrays``: the bands in column
+    blocks, which are the matrix's row blocks, as the program's
+    ``distributed.shard_specs`` places a ``BandedOperator``."""
+    from jax.sharding import PartitionSpec as P
+
+    return {"bands": P(None, axis)}
+
+
 def shapes(cfg: dict, arrays: dict) -> dict:
     return {"n": int(cfg["nx"]) * int(cfg["ny"]),
             "nbands": len(offsets(cfg))}
@@ -105,7 +114,10 @@ class HostSystem:
         self.bands = np.asarray(arrays["bands"], np.float64)
         self.offsets = offsets(cfg)
 
-    def residuals(self, xs: np.ndarray, bs: np.ndarray) -> np.ndarray:
+    def residuals(self, xs, bs) -> np.ndarray:
+        """Relative residuals ``|b - A x| / |b|`` of the answers ``xs``
+        and right-hand sides ``bs``, iterables read once, one answer at a
+        time."""
         out = []
         for x, b in zip(xs, bs):
             x = np.asarray(x, np.float64)

@@ -49,10 +49,12 @@ class HostSystem:
     def __init__(self, cfg: dict, arrays: dict):
         self.a = np.asarray(arrays["a"], np.float64)
 
-    def residuals(self, xs: np.ndarray, bs: np.ndarray) -> np.ndarray:
-        """Relative residuals ``|b - A x| / |b|`` of rows of xs and bs."""
-        xs = np.asarray(xs, np.float64)
-        bs = np.asarray(bs, np.float64)
+    def residuals(self, xs, bs) -> np.ndarray:
+        """Relative residuals ``|b - A x| / |b|`` of the answers ``xs``
+        and right-hand sides ``bs``, iterables read once: stacked, in one
+        product (a sample of N-vectors is small)."""
+        xs = np.asarray(list(xs), np.float64)
+        bs = np.asarray(list(bs), np.float64)
         r = bs - xs @ self.a.T
         return np.linalg.norm(r, axis=1) / np.linalg.norm(bs, axis=1)
 

@@ -19,6 +19,12 @@ entries, never an edit here.
                 window holds the requests due in it, and after it closes
                 the server is drained for up to ``DRAIN_S`` seconds.
 
+A cell whose ``chips`` is above 1 runs the closed loop through the
+program's row-sharded path: a one-axis mesh over that many devices, the
+family's arrays placed by its ``shard_specs``, and the program's ``gmres``
+called per shard with ``axis_name`` inside ``tuning.shard_context``, as
+``core/distributed.py`` calls it.  The cell's ``chips`` is the only switch.
+
 After the window: peak device memory is read, the program's state is
 dropped, and a seeded sample of the answers the window produced is checked
 on the host in float64 against the configuration's plain reference.
@@ -48,6 +54,13 @@ DRAIN_S = 60.0
 # the solve or tick in progress): enough for thousands of steps, and a
 # trace that reads back in seconds.
 TRACE_S = 5.0
+# A time stepper starts again from its first state every CHAIN_STEPS
+# steps (the warm-up is step 0): after about 1150 backward-Euler steps
+# the 1024^2 stepper's field underflows to zero, and a zero right-hand
+# side has no relative residual to check.
+CHAIN_STEPS = 1024
+# The mesh axis of a cell on several chips.
+AXIS = "rows"
 
 # The loops' clock, which the tests replace.
 _now = time.perf_counter
@@ -199,23 +212,58 @@ class CompileCounter:
 
 
 # --------------------------------------------------------------------------
+# A cell on several chips
+# --------------------------------------------------------------------------
+def sharded_mesh(family, cfg: dict, traffic: dict, chips: int):
+    """A one-axis mesh over the first ``chips`` devices, for a cell whose
+    family declares ``shard_specs`` and whose traffic is a closed loop."""
+    import jax
+    from jax.sharding import Mesh
+
+    if not hasattr(family, "shard_specs"):
+        raise ValueError(
+            f"the cell asks for {chips} chips, but the {cfg['system']!r} "
+            f"family declares no shard_specs(cfg, axis): it runs on one chip")
+    if traffic["loop"] != "closed":
+        raise ValueError(
+            f"the cell asks for {chips} chips, but its traffic runs the "
+            f"{traffic['loop']!r} loop, which runs on one chip; only the "
+            f"closed loop runs the program's sharded solve")
+    return Mesh(np.asarray(jax.devices()[:chips]), (AXIS,))
+
+
+def place(family, cfg: dict, mesh, arrays: dict, pool: list):
+    """The family's arrays placed by its ``shard_specs``, and each pool
+    vector row-sharded over the mesh."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    specs = family.shard_specs(cfg, AXIS)
+    arrays = {k: jax.device_put(v, NamedSharding(mesh, specs[k]))
+              for k, v in arrays.items()}
+    row = NamedSharding(mesh, P(AXIS))
+    return arrays, [jax.device_put(v, row) for v in pool]
+
+
+# --------------------------------------------------------------------------
 # The timed paths
 # --------------------------------------------------------------------------
-def make_solve(family, cfg: dict, traffic: dict, arrays: dict):
+def make_solve(family, cfg: dict, traffic: dict, arrays: dict, mesh=None):
     """The program's ``gmres`` as one jitted call, and its fixed arguments.
 
     A preconditioner named by the traffic is built once, in set-up, through
     the program's own ``make_preconditioner``; the timed call applies that
     object's own ``__call__`` with its arrays passed as jit arguments.
+    With a ``mesh`` (a cell on several chips) see ``make_sharded_solve``.
     """
+    if mesh is not None:
+        return make_sharded_solve(family, cfg, traffic, arrays, mesh)
     import jax
 
     import repro.core as core
     from repro.core import preconditioners
 
-    s = cfg["solver"]
-    kw = dict(m=int(s["m"]), tol=float(s["tol"]),
-              max_restarts=int(s["max_restarts"]), gs=traffic["gs"])
+    kw = _solver_kw(cfg, traffic)
     name = traffic.get("precond")
     if name is None:
         def solve(arrays, b, x0):
@@ -237,29 +285,113 @@ def make_solve(family, cfg: dict, traffic: dict, arrays: dict):
 
     def solve(arrays, state, b, x0):
         op = family.program_operator(cfg, arrays)
-        pc = copy.copy(template)
-        vars(pc).update(state)
-        if "op" in vars(pc):
-            pc.op = op
-        return core.gmres(op, b, x0, precond=pc, **kw)
+        return core.gmres(op, b, x0, precond=_bind(template, state, op),
+                          **kw)
 
     return jax.jit(solve), (arrays, state)
 
 
+def make_sharded_solve(family, cfg: dict, traffic: dict, arrays: dict,
+                       mesh):
+    """``make_solve`` for a cell on several chips: the program's
+    row-sharded path.
+
+    Each call runs per shard, a ``shard_map`` over ``AXIS`` with the
+    family's ``shard_specs``, traced inside ``tuning.shard_context``: the
+    shared cycle that ``distributed.gmres_sharded`` wraps, the program's
+    ``gmres`` with ``axis_name``.  The preconditioner is built in set-up
+    from each shard's own block of the operator (block-Jacobi over the
+    shards; ILU(0) of each block for ``banded_block_jacobi``); its arrays
+    come back with a leading axis of one entry a shard, whatever their own
+    layout, and each stays on its shard.  ``x`` comes back row-sharded.
+    """
+    import functools
+
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    import repro.core as core
+    from repro.core import preconditioners
+    from repro.core.gmres import GmresResult
+    from repro.kernels import tuning
+
+    chips = mesh.shape[AXIS]
+    specs, vec = family.shard_specs(cfg, AXIS), P(AXIS)
+    # As distributed._run_sharded: x row-sharded, the rest replicated.
+    result = GmresResult(*(P() for _ in GmresResult._fields))._replace(x=vec)
+    kw = dict(_solver_kw(cfg, traffic), axis_name=AXIS)
+
+    def per_shard(fn, in_specs, out_specs):
+        @functools.wraps(fn)
+        def local(*args):
+            with tuning.shard_context(AXIS, chips):
+                return fn(*args)
+        return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                                     out_specs=out_specs, check_vma=False))
+
+    name = traffic.get("precond")
+    if name is None:
+        def solve(arrays, b, x0):
+            return core.gmres(family.program_operator(cfg, arrays), b, x0,
+                              **kw)
+        return per_shard(solve, (specs, vec, vec), result), (arrays,)
+
+    made = []
+
+    def factor(arrays):
+        pc = preconditioners.make_preconditioner(
+            name, family.program_operator(cfg, arrays))
+        made.append(pc)
+        return {k: v[None] for k, v in vars(pc).items()
+                if isinstance(v, jax.Array)}
+
+    state = jax.block_until_ready(per_shard(factor, (specs,), vec)(arrays))
+    template = made[0]
+
+    def solve(arrays, state, b, x0):
+        op = family.program_operator(cfg, arrays)
+        pc = _bind(template, {k: v[0] for k, v in state.items()}, op)
+        return core.gmres(op, b, x0, precond=pc, **kw)
+
+    return per_shard(solve, (specs, vec, vec, vec), result), (arrays, state)
+
+
+def _solver_kw(cfg: dict, traffic: dict) -> dict:
+    s = cfg["solver"]
+    return dict(m=int(s["m"]), tol=float(s["tol"]),
+                max_restarts=int(s["max_restarts"]), gs=traffic["gs"])
+
+
+def _bind(template, state: dict, op):
+    """The preconditioner built in set-up, with its arrays ``state`` and
+    its operator ``op`` as the timed call holds them."""
+    pc = copy.copy(template)
+    vars(pc).update(state)
+    if "op" in vars(pc):
+        pc.op = op
+    return pc
+
+
 def closed_loop(ctx: "Context") -> List[tuple]:
-    """Solves back to back; returns the sampled answers ``(x, b, tol)``."""
+    """Solves back to back; returns the sampled answers ``(x, b, tol)``,
+    as the devices hold them."""
     import jax
 
-    solve, args = make_solve(ctx.family, ctx.cfg, ctx.traffic, ctx.arrays)
+    solve, args = make_solve(ctx.family, ctx.cfg, ctx.traffic, ctx.arrays,
+                             ctx.mesh)
     rows = ctx.pool
     order = generator.pool_order(ctx.traffic, ctx.seed)
     chain = bool(ctx.traffic.get("chain"))
 
     def inputs(i, prev):
-        """Right-hand side and first guess of solve ``i``; ``prev`` is
-        the answer of the solve before."""
+        """Right-hand side and first guess of solve ``i`` of the window;
+        ``prev`` is the answer of the solve before, None for the warm-up.
+        A time stepper's warm-up is its step 0 and solve ``i`` its step
+        ``i + 1``; every ``CHAIN_STEPS`` steps it takes the first state
+        again."""
         if chain:
-            b = rows[order[0]] if prev is None else prev
+            first = prev is None or (i + 1) % CHAIN_STEPS == 0
+            b = rows[order[0]] if first else prev
             return b, b
         return rows[order[i % len(order)]], None
 
@@ -308,7 +440,7 @@ def closed_loop(ctx: "Context") -> List[tuple]:
          "converged": bool(c[2]), "traced": k < traced}
         for k, (r, c) in enumerate(zip(results, counts))]
     tol = float(ctx.cfg["solver"]["tol"])
-    return [(np.asarray(x), np.asarray(b), tol) for x, b in sample]
+    return [(x, b, tol) for x, b in sample]
 
 
 def open_loop(ctx: "Context") -> List[tuple]:
@@ -462,6 +594,7 @@ class Context:
         self.tracing = False
         self.arrays: dict = {}
         self.pool: list = []
+        self.mesh = None
         self.run: Optional[Run] = None
 
     def span(self, name: str):
@@ -506,20 +639,34 @@ class Context:
             self.counter.open = False
 
 
+def build_system(ctx: Context) -> None:
+    """The family's arrays and right-hand-side pool, made on the device
+    from the seed; for a cell on several chips, placed over its mesh."""
+    import jax
+
+    chips = int(ctx.cell["chips"])
+    if chips > 1:
+        ctx.mesh = sharded_mesh(ctx.family, ctx.cfg, ctx.traffic, chips)
+    key = jax.random.PRNGKey(generator.key_int(ctx.seed, "system"))
+    ctx.arrays, pool = ctx.family.build(ctx.cfg, key,
+                                        int(ctx.traffic["pool"]))
+    ctx.pool = list(pool)
+    if ctx.mesh is not None:
+        ctx.arrays, ctx.pool = place(ctx.family, ctx.cfg, ctx.mesh,
+                                     ctx.arrays, ctx.pool)
+
+
 def run_cell(root, workload: str, seed: int, seconds: float, traced: bool,
              *, t_process: float, require_tpu: bool = True,
              counter: Optional[CompileCounter] = None) -> dict:
     """Run one cell once and return its result object."""
-    import jax
-
     layout = Layout(root)
     cell = layout.cell(workload)
-    device = device_info(int(cell["chips"]), require_tpu)
+    chips = int(cell["chips"])
+    device = device_info(chips, require_tpu)
     ctx = Context(layout, cell, seed, seconds, traced, t_process, counter)
     cfg, traffic = ctx.cfg, ctx.traffic
-    key = jax.random.PRNGKey(generator.key_int(seed, "system"))
-    ctx.arrays, pool = ctx.family.build(cfg, key, int(traffic["pool"]))
-    ctx.pool = list(pool)
+    build_system(ctx)
     peak_table = roofline.peaks(device["kind"]) if require_tpu else None
     ctx.run = Run(cell=cell, config=cfg, traffic=traffic, seed=int(seed),
                   seconds=float(seconds),
@@ -527,7 +674,7 @@ def run_cell(root, workload: str, seed: int, seconds: float, traced: bool,
                   peaks=peak_table)
     sample = LOOPS[traffic["loop"]](ctx)
     run = ctx.run
-    device["memory_peak_bytes"] = memory_peak(int(cell["chips"]))
+    device["memory_peak_bytes"] = memory_peak(chips)
     if traced:
         try:
             run.trace = trace_mod.Summary(trace_mod.read_xplane(ctx.trace_dir))
@@ -560,12 +707,17 @@ def run_cell(root, workload: str, seed: int, seconds: float, traced: bool,
 
 def check_answers(host, sample: List[tuple], cfg: dict) -> dict:
     """The widest ratio of a sampled answer's float64 relative residual to
-    its tolerance, beside the configuration's limit."""
+    its tolerance, beside the configuration's limit.
+
+    The family's ``HostSystem.residuals`` reads the answers and right-hand
+    sides once, in order, and each is copied to the host (gathered from
+    its shards) only when it is read: a stencil's residuals take one
+    answer at a time, so the host holds one answer, not the sample."""
     limit = float(cfg["limits"]["resid_over_tol"])
     if not sample:
         return {"resid_over_tol_max": {"value": math.inf, "limit": limit}}
-    xs = np.stack([np.asarray(x, np.float32) for x, _, _ in sample])
-    bs = np.stack([np.asarray(b, np.float32) for _, b, _ in sample])
+    xs = (np.asarray(x, np.float32) for x, _, _ in sample)
+    bs = (np.asarray(b, np.float32) for _, b, _ in sample)
     tols = np.asarray([t for _, _, t in sample])
     ratios = host.residuals(xs, bs) / tols
     return {"resid_over_tol_max": {"value": float(np.max(ratios)),

@@ -1,6 +1,6 @@
 """plain_step_roofline: the whole time step's share of the HBM roofline
 (with no preconditioner): the least bytes of every traced step's solve at peak
-bandwidth, over the traced window."""
+bandwidth of all the cell's chips, over the traced window."""
 
 from bench import roofline
 
@@ -10,4 +10,4 @@ def read(run):
         return None
     nbytes = sum(sum(roofline.solve_parts(run, s).values())
                  for s in run.solves if s["traced"])
-    return roofline.share(run, nbytes, run.trace.window_s)
+    return roofline.window_share(run, nbytes)

@@ -1,6 +1,6 @@
 """solve_roofline: the whole solve's share of the HBM roofline: the least
 bytes of every traced solve (operator and orthogonalization) at peak
-bandwidth, over the traced window."""
+bandwidth of all the cell's chips, over the traced window."""
 
 from bench import roofline
 
@@ -10,4 +10,4 @@ def read(run):
         return None
     nbytes = sum(sum(roofline.solve_parts(run, s).values())
                  for s in run.solves if s["traced"])
-    return roofline.share(run, nbytes, run.trace.window_s)
+    return roofline.window_share(run, nbytes)

@@ -56,10 +56,8 @@ def _traced_run(root, workload: str, seed: int, seconds: float,
     """Run the cell's loop with the profiler on; returns the context and
     the program objects the loop made (its jitted solve, or its server,
     and the first rid of the window)."""
-    import jax
-
     import repro.serve
-    from bench import generator, harness
+    from bench import harness
 
     kept = {}
     layout = harness.Layout(root)
@@ -84,10 +82,7 @@ def _traced_run(root, workload: str, seed: int, seconds: float,
         return out
 
     ctx = Ctx(layout, cell, seed, seconds, True, time.perf_counter(), None)
-    key = jax.random.PRNGKey(generator.key_int(seed, "system"))
-    ctx.arrays, pool = ctx.family.build(ctx.cfg, key,
-                                        int(ctx.traffic["pool"]))
-    ctx.pool = list(pool)
+    harness.build_system(ctx)
     ctx.run = harness.Run(cell=cell, config=ctx.cfg, traffic=ctx.traffic,
                           seed=seed, seconds=seconds,
                           shapes=ctx.family.shapes(ctx.cfg, ctx.arrays),

@@ -141,9 +141,19 @@ def share(run, nbytes: float, seconds: float):
     return 100.0 * nbytes / run.peaks["hbm_bytes_per_s"] / seconds
 
 
+def window_share(run, nbytes: float):
+    """Percent of the HBM roofline of all the cell's chips: ``nbytes`` at
+    the peak of one chip times the chips, over the traced window."""
+    if run.trace is None:
+        return None
+    pct = share(run, nbytes, run.trace.window_s)
+    return None if pct is None else pct / int(run.cell["chips"])
+
+
 def kernel_share(run, parts, kernels):
     """Roofline share of the traced solves' ``parts`` over the device time
-    of the ops named ``kernels`` in the traced window."""
+    of the ops named ``kernels`` in the traced window, summed over the
+    chips: one chip's peak over the time of all of them."""
     if run.trace is None or not run.solves:
         return None
     t = run.trace.kernel_s(kernels)

@@ -1,5 +1,5 @@
-"""Reduction of a profiler trace to device busy time, kernel time and idle
-gaps.
+"""Reduction of a profiler trace to device busy time, kernel time, idle
+gaps and, on several chips, each chip's busy and collective time.
 
 ``read_xplane`` turns the ``.xplane.pb`` file that ``jax.profiler`` writes
 into plain event lists; everything else works on those lists, so the
@@ -22,6 +22,10 @@ SPAN_PREFIX = "bench."
 WINDOW_SPAN = "bench.window"
 _SUFFIX = re.compile(r"(\.\d+|\.clone)+$")
 _ENCLOSING = re.compile(r"^(while|conditional|call)$")
+# Ops that move data between chips, synchronous or split in a start and a
+# done (``all-reduce-start``, ``collective-permute-done``).
+_COLLECTIVE = re.compile(r"^(all-reduce|collective-permute|all-gather"
+                         r"|reduce-scatter|all-to-all)")
 
 
 def base_name(op: str) -> str:
@@ -84,6 +88,11 @@ def clip(events: Iterable[Event], start: float, end: float) -> List[Event]:
     return out
 
 
+def is_collective(op: str) -> bool:
+    """An op that exchanges data between chips."""
+    return bool(_COLLECTIVE.match(base_name(op)))
+
+
 def intervals(events: Iterable[Event]) -> List[Tuple[float, float]]:
     """The union of the events' intervals, as sorted disjoint pairs."""
     merged: List[List[float]] = []
@@ -98,6 +107,31 @@ def intervals(events: Iterable[Event]) -> List[Tuple[float, float]]:
 def busy_ns(events: Iterable[Event]) -> float:
     """Time in which at least one op ran."""
     return sum(b - a for a, b in intervals(events))
+
+
+def overlap_ns(a: Sequence[Tuple[float, float]],
+               b: Sequence[Tuple[float, float]]) -> float:
+    """Time that two lists of sorted disjoint intervals share."""
+    total, i, j = 0.0, 0, 0
+    while i < len(a) and j < len(b):
+        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if hi > lo:
+            total += hi - lo
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
+
+
+def exposed_collective_ns(events: Iterable[Event]) -> float:
+    """Time in which a collective ran and no other op did.  Control flow,
+    whose events span the ops it runs, hides nothing."""
+    events = list(events)
+    coll = intervals(e for e in events if is_collective(e[0]))
+    other = intervals(e for e in events
+                      if not is_collective(e[0]) and not encloses(e[0]))
+    return sum(b - a for a, b in coll) - overlap_ns(coll, other)
 
 
 def kernel_ns(events: Iterable[Event], names: Iterable[str]) -> float:
@@ -173,6 +207,21 @@ class Summary:
         """Device time of the named ops, summed over the planes."""
         names = list(names)
         return sum(kernel_ns(e, names) for e in self.ops.values()) * 1e-9
+
+    def busy_by_plane(self) -> Dict[str, float]:
+        """Busy seconds of each plane: the busiest chip and the idlest."""
+        return {p: busy_ns(e) * 1e-9 for p, e in self.ops.items()}
+
+    def collective_by_plane(self) -> Dict[str, float]:
+        """Device seconds of each plane's collective ops."""
+        return {p: sum(d for n, _, d in e if is_collective(n)) * 1e-9
+                for p, e in self.ops.items()}
+
+    def exposed_collective_by_plane(self) -> Dict[str, float]:
+        """Seconds of each plane in which a collective ran and no compute
+        op overlapped it."""
+        return {p: exposed_collective_ns(e) * 1e-9
+                for p, e in self.ops.items()}
 
     def all_ops(self) -> List[Event]:
         return [e for evs in self.ops.values() for e in evs]

@@ -1,10 +1,12 @@
 """The chip benchmark's harness on the CPU, at sizes a test run holds.
 
 Covers the traffic generator, the window rules of the end-to-end metrics
-on a fake clock, the trace reduction against a recorded trace, the refusal
-of a CPU device and of an unknown device kind, the names and units of
-``BENCHMARK.json``, a cell defined only here, the check refusing each
-control, and the check refusing runs whose timed path is broken.
+on a fake clock, the time stepper's return to its first state, the trace
+reduction against a recorded trace, the refusal of a CPU device and of an
+unknown device kind, the names and units of ``BENCHMARK.json``, a cell
+defined only here, the check reading one answer at a time, the check
+refusing each control, and the check refusing runs whose timed path is
+broken.
 """
 from __future__ import annotations
 
@@ -56,6 +58,11 @@ def _tiny_root(tmp_path: pathlib.Path) -> pathlib.Path:
     (tmp_path / "bench/traffic/tiny_serve.json").write_text(json.dumps({
         "loop": "open", "rate": 200.0, "tol_loguniform": [1e-6, 1e-4],
         "pool": 8, "check_sample": 16}))
+    # Ten times the rate: more requests in flight than the server has
+    # lanes, so every lane serves some, whatever the host's speed.
+    (tmp_path / "bench/traffic/tiny_burst.json").write_text(json.dumps({
+        "loop": "open", "rate": 2000.0, "tol_loguniform": [1e-6, 1e-4],
+        "pool": 8, "check_sample": 16}))
     (tmp_path / "bench/metrics/solves_in_window.py").write_text(
         "def read(run):\n    return float(len(run.solves)) or None\n")
     spec = {
@@ -68,7 +75,9 @@ def _tiny_root(tmp_path: pathlib.Path) -> pathlib.Path:
             {"name": "tiny.ilu0", "config": "tiny_conv",
              "traffic": "timestep_ilu0", "chips": 1},
             {"name": "tiny.serve", "config": "tiny_dense",
-             "traffic": "tiny_serve", "chips": 1}],
+             "traffic": "tiny_serve", "chips": 1},
+            {"name": "tiny.serve_burst", "config": "tiny_dense",
+             "traffic": "tiny_burst", "chips": 1}],
         "end_to_end": [dict(m) for m in SPEC["end_to_end"]],
         "per_layer": [
             {"name": "solves_in_window", "unit": "solves", "better": "higher",
@@ -80,7 +89,8 @@ def _tiny_root(tmp_path: pathlib.Path) -> pathlib.Path:
              "step_ms.plain": []}
     for m in spec["end_to_end"]:
         if "workloads" in m:
-            m["workloads"] = cells.get(m["name"], ["tiny.serve"])
+            m["workloads"] = cells.get(m["name"],
+                                       ["tiny.serve", "tiny.serve_burst"])
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
     return tmp_path
 
@@ -245,6 +255,32 @@ def test_time_stepper_chains_its_answers(tiny_root, monkeypatch):
         np.testing.assert_array_equal(x0, b)
     reader = harness.Layout(tiny_root).reader("step_ms.ilu0")
     assert reader.read(ctx.run) == pytest.approx(250.0)
+
+
+def test_time_stepper_starts_again_every_1024_steps(tiny_root,
+                                                    monkeypatch):
+    """Step 1024 (the warm-up is step 0) takes the first state, with its
+    seeded sign, as its right-hand side and first guess, before the field
+    can underflow; the steps on either side chain."""
+    clock = FakeClock()
+    seen = []
+
+    def fake_solve(b, x0):
+        clock.t += 1e-3
+        seen.append((np.asarray(b), np.asarray(x0)))
+        return Result(np.asarray(b) + 1.0, np.int32(3), np.int32(1),
+                      np.bool_(True))
+
+    monkeypatch.setattr(harness, "make_solve", lambda *a: (fake_solve, ()))
+    ctx = _ctx(tiny_root, "tiny.ilu0", 1.03, monkeypatch, clock)
+    first = np.full(2, 10.0)
+    ctx.pool = [first]
+    harness.closed_loop(ctx)
+    assert harness.CHAIN_STEPS == 1024 and len(seen) > 1026
+    np.testing.assert_array_equal(seen[1024][0], first)
+    for step, (b, x0) in enumerate(seen):
+        np.testing.assert_array_equal(x0, b)
+        np.testing.assert_array_equal(b, first + step % 1024)
 
 
 def test_latency_runs_from_the_due_time(tiny_root):
@@ -482,6 +518,32 @@ def test_control_is_refused_and_program_is_not(tiny_root, ref_kernels, cell):
     assert res["correct"] and check["value"] <= check["limit"]
 
 
+@pytest.mark.parametrize("cell", ["tiny.solve", "tiny.ilu0"])
+def test_check_one_answer_at_a_time_reads_as_the_stacked_check(tiny_root,
+                                                               cell):
+    """The check hands the family its answers as they lie on the device,
+    copied to the host one at a time, and reads bit for bit what the
+    family reads from all of them stacked."""
+    import jax
+    import jax.numpy as jnp
+
+    layout = harness.Layout(tiny_root)
+    cfg = layout.config(layout.cell(cell))
+    family = layout.family(cfg)
+    arrays, pool = family.build(cfg, jax.random.PRNGKey(3), 4)
+    host = family.HostSystem(cfg, arrays)
+    rng = np.random.default_rng(5)
+    sample = [(jnp.asarray(pool[i % 4] + 1e-5 * rng.standard_normal(
+        pool.shape[1]), jnp.float32), pool[i % 4], 1e-5 * (1 + i % 3))
+        for i in range(12)]
+    xs = np.stack([np.asarray(x, np.float32) for x, _, _ in sample])
+    bs = np.stack([np.asarray(b, np.float32) for _, b, _ in sample])
+    tols = np.asarray([t for _, _, t in sample])
+    stacked = float(np.max(host.residuals(xs, bs) / tols))
+    got = harness.check_answers(host, sample, cfg)["resid_over_tol_max"]
+    assert got["value"] == stacked and got["value"] > 0
+
+
 def _break_solve(monkeypatch, how):
     import jax.numpy as jnp
 
@@ -526,7 +588,10 @@ def test_broken_server_is_not_correct(tiny_root, ref_kernels, monkeypatch,
         return x2 * 1.001, beta, inner
 
     monkeypatch.setattr(handles, "gmres_batched_cycle", broken)
-    res = _run(tiny_root, "tiny.serve")
+    # The server fills its lowest idle lane first: at the tiny cell's rate
+    # a fast host keeps four or fewer requests in flight and never uses
+    # the lanes left out, so the burst, which fills every lane, is run.
+    res = _run(tiny_root, "tiny.serve_burst")
     assert not res["correct"]
 
 
@@ -535,3 +600,10 @@ def test_server_cell_is_correct(tiny_root, ref_kernels):
     assert res["correct"] and res["attempted"] >= 50
     assert set(res["metrics"]) == {"serve_latency_ms_p50",
                                    "serve_solves_per_s", "setup_s"}
+
+
+def test_server_burst_cell_is_correct(tiny_root, ref_kernels):
+    """The burst the broken server is refused on reads correct with the
+    server as it is."""
+    res = _run(tiny_root, "tiny.serve_burst")
+    assert res["correct"] and res["attempted"] >= 500
